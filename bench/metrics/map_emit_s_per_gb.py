@@ -1,0 +1,10 @@
+"""Summed ``map:emit`` span seconds of the jobs completed in the window,
+per GB of corpus those jobs covered."""
+
+from __future__ import annotations
+
+from metrics._stages import seconds_per_gb
+
+
+def read(ctx: dict) -> float | None:
+    return seconds_per_gb(ctx, "map:emit")
