@@ -44,10 +44,37 @@ EVENTS: dict[str, Event] = {
     "scan:*": Event(
         ("span",), "engine", ("utils/spans.py", "ops/engine.py"),
         ("runtime/explain.py",),
-        "Per-scan engine span promoted from engine.stats; the member is the "
-        "kernel family that ran (scan:batch = one packed cross-file flush).",
+        "Per-scan engine span promoted from engine.stats, open over the "
+        "scan (the engine:* spans are its children); the member is the "
+        "kernel family that ran (scan:batch = one packed cross-file flush); "
+        "args.compiles = backend compiles the scan waited on.",
         members=("re", "native", "dfa", "nfa", "shift_and", "fdr",
                  "pairset", "approx", "batch"),
+    ),
+    "engine:prep": Event(
+        ("span",), "engine", ("ops/engine.py", "ops/device_scan.py"), (),
+        "Host work before a dispatch: content-key, index and corpus-cache "
+        "lookups, input reads and copies, newline index, segment layout "
+        "and upload, the feed's residual stall.",
+    ),
+    "engine:wait": Event(
+        ("span",), "engine", ("ops/device_scan.py",), (),
+        "Host blocked on the device: kernel dispatch (and any compile) and "
+        "the sparse result fetch (count + gather + transfer).",
+    ),
+    "engine:decode": Event(
+        ("span",), "engine", ("ops/device_scan.py",), (),
+        "Sparse words/spans -> offsets -> candidate or matched lines.",
+    ),
+    "engine:confirm": Event(
+        ("span",), "engine", ("ops/device_scan.py",), (),
+        "Host confirm of device candidates (per-line, native dense pass, "
+        "FDR ConfirmSet).",
+    ),
+    "engine:stitch": Event(
+        ("span",), "engine", ("ops/engine.py", "ops/device_scan.py"), (),
+        "Stripe/segment boundary re-scan, the nullable-$ EOL fix-up and "
+        "the final sort into the ScanResult.",
     ),
     # ---------------------------------------------------------- worker spans
     "map:task": Event(
@@ -70,6 +97,11 @@ EVENTS: dict[str, Event] = {
     "map:shuffle": Event(
         ("span",), "map", ("runtime/worker.py",), (),
         "Bucketize + mr-out partition writes for one map attempt.",
+    ),
+    "shuffle:build": Event(
+        ("span",), "map", ("runtime/worker.py",), (),
+        "Bucketize inside map:shuffle, where the deferred native record "
+        "build runs; the rest of map:shuffle is the partition writes.",
     ),
     "reduce:task": Event(
         ("span",), "reduce", ("runtime/worker.py",), (),
@@ -165,6 +197,13 @@ EVENTS: dict[str, Event] = {
         ("instant",), "follow", ("runtime/follow.py",),
         ("runtime/explain.py",),
         "Solo follow wake (including a joiner's catch-up poll).",
+    ),
+    # ------------------------------------------------------ scheduler spans
+    "*:wait": Event(
+        ("span",), "sched", ("runtime/scheduler.py",), (),
+        "A task's wait for a worker: runnable (map enqueue/re-enqueue, "
+        "reduce at map-phase completion) to assigned; task in args.",
+        members=("map", "reduce"),
     ),
     # ---------------------------------------------------- scheduler instants
     "assign_map": Event(

@@ -16,11 +16,13 @@ import itertools as _itertools
 import os as _os
 import threading as _threading_mod
 import time as _time_mod
+from contextlib import contextmanager
 
 import numpy as np
 
 from distributed_grep_tpu.models.fdr import FdrError, compile_fdr
 from distributed_grep_tpu.ops import lines as lines_mod
+from distributed_grep_tpu.utils import lockdep as _lockdep
 from distributed_grep_tpu.utils import spans as spans_mod
 from distributed_grep_tpu.ops import engine as _engine_mod
 from distributed_grep_tpu.ops.engine import (
@@ -249,6 +251,31 @@ def maybe_retune_fdr(eng, n_bytes: int) -> None:
 
 
 
+# jax.monitoring's event for one backend compile (a persistent-cache load
+# is not one).
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_lock = _lockdep.make_lock("compile-listener")
+_compile_listener_on = False
+
+
+def _listen_for_compiles() -> None:
+    """Register, once per process, the jax.monitoring listener that counts
+    each backend compile into the scan span open on the compiling thread
+    (utils/spans.count_compile): the scan record's ``compiles``."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        import jax
+
+        def on_duration(event: str, _secs: float, **_kw) -> None:
+            if event == _BACKEND_COMPILE_EVENT:
+                spans_mod.count_compile()
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _compile_listener_on = True
+
+
 def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     import time as _time
 
@@ -266,8 +293,6 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     # converting an internal callback bug into a plain stamp and
     # losing the compile-grace declaration (round-4 ADVICE).
     supports_grace = progress is not None and _accepts_grace_kwarg(progress)
-    nl = lines_mod.newline_index(data)
-    eng._nl_local.stash = (len(data), nl)  # reused by scan()'s EOL leg
     device_lines: set[int] = set()
     boundaries: list[int] = []
     seg = eng.segment_bytes
@@ -357,6 +382,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     import jax
     from contextlib import nullcontext
 
+    if spans_mod.active():
+        _listen_for_compiles()
     if eng.devices == "all":
         devs: list = list(jax.local_devices())
     elif eng.devices:
@@ -425,48 +452,6 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
             target_lanes=eng.target_lanes, quantize_chunk=True
         )
 
-    # Device corpus cache (ops/layout.CorpusCache): when the caller
-    # threaded a content key and a byte budget is in force, a resident
-    # variant replaces the whole host-pad + upload pipeline for this
-    # scan; a miss records the built segments and publishes them after
-    # the scan SUCCEEDS (fallback/rescue paths never publish partial
-    # state).  Mesh engines and explicit device lists bypass via
-    # _corpus_budget() == 0 — same verdict as the model cache: resident
-    # segments are committed to specific devices.  Inputs LARGER than
-    # the budget are cache-ineligible outright: retaining their built
-    # segments until scan end would defeat the double-buffer's bounded
-    # footprint, and publishing them would LRU-wipe every smaller
-    # entry before the oversized newcomer evicts itself.
-    resident = None  # [(seg_start, Layout, device_array, dev)] when warm
-    corpus_put = None  # (cache, sig, budget) when this scan populates
-    if corpus_key is not None and eng.mesh is None and len(data) > 0:
-        budget = eng._corpus_budget()
-        # Eligibility is priced on the PADDED device bytes, computed
-        # upfront from the hoisted lay_kwargs (choose_layout is pure
-        # arithmetic): gating on raw len(data) alone would let the
-        # raw<=budget<padded band set corpus_put, retain every built
-        # segment until scan end, and then have put_segments decline
-        # the publish — paying the retention on every repeat query.
-        if budget > 0:
-            n_full, tail = divmod(len(data), eng.segment_bytes)
-            padded_total = n_full * layout_mod.choose_layout(
-                eng.segment_bytes, **lay_kwargs
-            ).padded if n_full else 0
-            if tail:
-                padded_total += layout_mod.choose_layout(
-                    tail, **lay_kwargs
-                ).padded
-        if budget > 0 and padded_total <= budget:
-            cache = layout_mod.corpus_cache()
-            sig = (eng.segment_bytes, tuple(sorted(lay_kwargs.items())))
-            resident = cache.resident_segments(corpus_key, sig)
-            if resident is None:
-                corpus_put = (cache, sig, budget)
-            spans_mod.instant(
-                f"corpus:{'hit' if resident is not None else 'miss'}",
-                cat="engine", bytes=len(data),
-            )
-
     # Scan-local NFA model state: the defeat guard below may swap the
     # relaxed filter for the exact automaton mid-scan (this scan only).
     nfa_model = eng.glushkov
@@ -484,15 +469,26 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     state_lock = _lockdep_mod.make_lock("device-scan-state")
     confirm_active = [0]  # live confirm legs; peak recorded in stats
 
-    def _confirm_enter() -> None:
+    @contextmanager
+    def confirming():
+        """One host confirm leg: its engine:confirm span, and the live-leg
+        count whose peak lands in stats."""
         with state_lock:
             confirm_active[0] += 1
             if confirm_active[0] > st.get("confirm_concurrency_peak", 0):
                 st["confirm_concurrency_peak"] = confirm_active[0]
+        try:
+            with spans_mod.span("engine:confirm", cat="engine"):
+                yield
+        finally:
+            with state_lock:
+                confirm_active[0] -= 1
 
-    def _confirm_exit() -> None:
-        with state_lock:
-            confirm_active[0] -= 1
+    def fetch(payload) -> tuple[np.ndarray, np.ndarray]:
+        """The sparse fetch of one result plane: the host blocks here
+        until the segment's kernels are done (engine:wait)."""
+        with spans_mod.span("engine:wait", cat="engine"):
+            return scan_jnp.sparse_nonzero(payload)
 
     def confirm_lines(cand) -> None:
         """Per-line host confirm for a sparse candidate-line set (the
@@ -560,30 +556,31 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                 # overlapped with the next segment's device scan.  The
                 # SWAR variant packs 4 stripes per word; its decoder
                 # demuxes byte-plane flags to the same span starts.
-                idx, vals = scan_jnp.sparse_nonzero(payload)
-                if sparse_kind == "span_words_packed":
-                    starts = sparse_mod.span_starts_from_packed_words(
-                        idx, vals, lay
-                    )
-                else:
-                    starts = sparse_mod.span_starts_from_sparse_words(idx, lay)
-                if starts.size:
-                    g0 = starts + seg_start  # global span starts
-                    g1 = np.minimum(g0 + 32, len(data))
-                    l0 = lines_mod.line_of_offsets(g0 + 1, nl)
-                    l1 = lines_mod.line_of_offsets(g1, nl)
+                idx, vals = fetch(payload)
+                with spans_mod.span("engine:decode", cat="engine"):
+                    if sparse_kind == "span_words_packed":
+                        starts = sparse_mod.span_starts_from_packed_words(
+                            idx, vals, lay
+                        )
+                    else:
+                        starts = sparse_mod.span_starts_from_sparse_words(
+                            idx, lay
+                        )
                     cand = set()
-                    for a, b in zip(l0.tolist(), l1.tolist()):
-                        cand.update(range(a, b + 1))
-                    with state_lock:
-                        cand -= device_lines  # already confirmed earlier
-                        st["candidates"] += len(cand)
+                    if starts.size:
+                        g0 = starts + seg_start  # global span starts
+                        g1 = np.minimum(g0 + 32, len(data))
+                        l0 = lines_mod.line_of_offsets(g0 + 1, nl)
+                        l1 = lines_mod.line_of_offsets(g1, nl)
+                        for a, b in zip(l0.tolist(), l1.tolist()):
+                            cand.update(range(a, b + 1))
+                        with state_lock:
+                            cand -= device_lines  # already confirmed
+                            st["candidates"] += len(cand)
+                if starts.size:
                     if len(cand) > _engine_mod.SPAN_CONFIRM_LINE_LIMIT:
-                        _confirm_enter()
-                        try:
+                        with confirming():
                             true_lines = dense_native_confirm(seg_start, seg_len)
-                        finally:
-                            _confirm_exit()
                         nonlocal sa_filtered
                         if sa_filtered is not None and true_lines * 4 < len(cand):
                             # mostly-false candidates: the corpus defeats
@@ -601,11 +598,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                             with state_lock:
                                 sa_filtered = None
                     else:
-                        _confirm_enter()
-                        try:
+                        with confirming():
                             confirm_lines(cand)
-                        finally:
-                            _confirm_exit()
                 return
             if sparse_kind == "cand_words":
                 # NFA filter path (models/nfa.compile_scan_model): the
@@ -613,23 +607,26 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                 # repeats relaxed to save state words); confirm each
                 # candidate line on host — overlapped with the next
                 # segment's device scan.
-                idx, vals = scan_jnp.sparse_nonzero(payload)
-                offsets = sparse_mod.offsets_from_sparse_words(idx, vals, lay)
-                with state_lock:
-                    st["candidates"] += int(offsets.size)
-                if offsets.size:
-                    t0 = _time.perf_counter()
-                    glines = lines_mod.line_of_offsets(offsets + seg_start, nl)
-                    cand = set(np.unique(glines).tolist())
+                idx, vals = fetch(payload)
+                with spans_mod.span("engine:decode", cat="engine"):
+                    offsets = sparse_mod.offsets_from_sparse_words(
+                        idx, vals, lay
+                    )
                     with state_lock:
-                        cand -= device_lines
+                        st["candidates"] += int(offsets.size)
+                    t0 = _time.perf_counter()
+                    if offsets.size:
+                        glines = lines_mod.line_of_offsets(
+                            offsets + seg_start, nl
+                        )
+                        cand = set(np.unique(glines).tolist())
+                        with state_lock:
+                            cand -= device_lines
+                if offsets.size:
                     if len(cand) > _engine_mod.SPAN_CONFIRM_LINE_LIMIT and \
                             eng.table is not None:
-                        _confirm_enter()
-                        try:
+                        with confirming():
                             true_lines = dense_native_confirm(seg_start, seg_len)
-                        finally:
-                            _confirm_exit()
                         nonlocal nfa_model, nfa_is_filter
                         if (
                             nfa_is_filter
@@ -655,30 +652,27 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                                 nfa_is_filter = False
                                 st["nfa_filter_defeated"] = True
                     else:
-                        _confirm_enter()
-                        try:
+                        with confirming():
                             confirm_lines(cand)
-                        finally:
-                            _confirm_exit()
                     with state_lock:
                         st["confirm_seconds"] += _time.perf_counter() - t0
                 return
             if sparse_kind == "words":
-                idx, vals = scan_jnp.sparse_nonzero(payload)
-                offsets = sparse_mod.offsets_from_sparse_words(idx, vals, lay)
+                idx, vals = fetch(payload)
+                with spans_mod.span("engine:decode", cat="engine"):
+                    offsets = sparse_mod.offsets_from_sparse_words(
+                        idx, vals, lay
+                    )
                 if use_fdr:
                     # Exact per-candidate confirm (suffix probe + memcmp)
                     # against the WHOLE document, so a window reaching
                     # back across the segment start still confirms; runs
                     # here so it overlaps the next segment's device scan.
                     t0 = _time.perf_counter()
-                    _confirm_enter()
-                    try:
+                    with confirming():
                         keep = eng._fdr_confirm.confirm(
                             data, offsets + seg_start
                         )
-                    finally:
-                        _confirm_exit()
                     with state_lock:
                         st["confirm_seconds"] += (
                             _time.perf_counter() - t0
@@ -686,28 +680,35 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                         st["candidates"] += int(offsets.size)
                     offsets = offsets[keep]
             elif sparse_kind == "lane_bytes":
-                idx, vals = scan_jnp.sparse_nonzero(payload)
-                offsets = sparse_mod.offsets_from_sparse_lane_bytes(idx, vals, lay)
+                idx, vals = fetch(payload)
+                with spans_mod.span("engine:decode", cat="engine"):
+                    offsets = sparse_mod.offsets_from_sparse_lane_bytes(
+                        idx, vals, lay
+                    )
             else:  # "bank_list": one packed plane per DFA bank
                 per_bank = []
                 for packed in payload:
-                    idx, vals = scan_jnp.sparse_nonzero(packed)
-                    per_bank.append(
-                        sparse_mod.offsets_from_sparse_lane_bytes(idx, vals, lay)
-                    )
+                    idx, vals = fetch(packed)
+                    with spans_mod.span("engine:decode", cat="engine"):
+                        per_bank.append(
+                            sparse_mod.offsets_from_sparse_lane_bytes(
+                                idx, vals, lay
+                            )
+                        )
                 offsets = np.unique(np.concatenate(per_bank)) if per_bank else \
                     np.zeros(0, dtype=np.int64)
         with state_lock:
             st["end_offsets"] += int(offsets.size)
         if offsets.size:
-            # transient slice: jobs hold (start, len), not segment copies
-            seg_view = data[seg_start : seg_start + seg_len]
-            seg_nl = lines_mod.newline_index(seg_view)
-            # offsets are np.unique output (sorted): native linear merge
-            seg_lines = lines_mod.unique_match_lines(offsets, seg_nl)
-            base = int(np.searchsorted(nl, seg_start))  # lines before segment
-            with state_lock:
-                device_lines.update((seg_lines + base).tolist())
+            with spans_mod.span("engine:decode", cat="engine"):
+                # transient slice: jobs hold (start, len), not segment copies
+                seg_view = data[seg_start : seg_start + seg_len]
+                seg_nl = lines_mod.newline_index(seg_view)
+                # offsets are np.unique output (sorted): native linear merge
+                seg_lines = lines_mod.unique_match_lines(offsets, seg_nl)
+                base = int(np.searchsorted(nl, seg_start))  # lines before
+                with state_lock:
+                    device_lines.update((seg_lines + base).tolist())
 
     # Double-buffered device feed (VERDICT r2 item 4): a one-slot
     # prepare thread builds segment i+1's stripe layout (host pad +
@@ -717,8 +718,6 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     # stats["feed_wait_seconds"] is the residual stall: ~0 when compute
     # hides the feed, ~upload time when the scan is feed-bound.
     
-    seg_starts = list(range(0, max(len(data), 1), seg))
-
     from distributed_grep_tpu.utils import trace as trace_mod
 
     def prepare(i: int, seg_start: int):
@@ -752,31 +751,79 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                 arr = jnp.asarray(arr)
         return seg_bytes, lay, arr, dev
 
-    pool = (
-        _DaemonPool(1, thread_name_prefix="dgrep-feed")
-        if len(seg_starts) > 1 and resident is None else None
-    )
-    # Collect pool (VERDICT r3 item 1): sparse decode + host confirm of
-    # finished segments runs here, so confirms from different devices'
-    # segments overlap each other and the dispatch loop instead of
-    # serializing on it.  Mesh mode has one sharded stream — two workers
-    # cover decode/confirm pipelining; round-robin mode sizes to the
-    # device fan.  Single-segment scans collect inline (nothing to
-    # overlap).
-    from collections import deque as _deque
+    # The newline index, the corpus-cache lookup and the first segment's
+    # host layout and upload: the scan's host preparation (engine:prep).
+    with spans_mod.span("engine:prep", cat="engine"):
+        nl = lines_mod.newline_index(data)
+        eng._nl_local.stash = (len(data), nl)  # reused by scan()'s EOL leg
+        # Device corpus cache (ops/layout.CorpusCache): when the caller
+        # threaded a content key and a byte budget is in force, a resident
+        # variant replaces the whole host-pad + upload pipeline for this
+        # scan; a miss records the built segments and publishes them after
+        # the scan SUCCEEDS (fallback/rescue paths never publish partial
+        # state).  Mesh engines and explicit device lists bypass via
+        # _corpus_budget() == 0 — same verdict as the model cache: resident
+        # segments are committed to specific devices.  Inputs LARGER than
+        # the budget are cache-ineligible outright: retaining their built
+        # segments until scan end would defeat the double-buffer's bounded
+        # footprint, and publishing them would LRU-wipe every smaller
+        # entry before the oversized newcomer evicts itself.
+        resident = None  # [(seg_start, Layout, device_array, dev)] when warm
+        corpus_put = None  # (cache, sig, budget) when this scan populates
+        if corpus_key is not None and eng.mesh is None and len(data) > 0:
+            budget = eng._corpus_budget()
+            # Eligibility is priced on the PADDED device bytes, computed
+            # upfront from the hoisted lay_kwargs (choose_layout is pure
+            # arithmetic): gating on raw len(data) alone would let the
+            # raw<=budget<padded band set corpus_put, retain every built
+            # segment until scan end, and then have put_segments decline
+            # the publish — paying the retention on every repeat query.
+            if budget > 0:
+                n_full, tail = divmod(len(data), eng.segment_bytes)
+                padded_total = n_full * layout_mod.choose_layout(
+                    eng.segment_bytes, **lay_kwargs
+                ).padded if n_full else 0
+                if tail:
+                    padded_total += layout_mod.choose_layout(
+                        tail, **lay_kwargs
+                    ).padded
+            if budget > 0 and padded_total <= budget:
+                cache = layout_mod.corpus_cache()
+                sig = (eng.segment_bytes, tuple(sorted(lay_kwargs.items())))
+                resident = cache.resident_segments(corpus_key, sig)
+                if resident is None:
+                    corpus_put = (cache, sig, budget)
+                spans_mod.instant(
+                    f"corpus:{'hit' if resident is not None else 'miss'}",
+                    cat="engine", bytes=len(data),
+                )
 
-    n_collect = 2 if use_mesh else min(4, max(1, len(devs)))
-    collect_pool = (
-        _DaemonPool(n_collect, thread_name_prefix="dgrep-collect")
-        if len(seg_starts) > 1 else None
-    )
-    collect_futs: _deque = _deque()
-    st["feed_wait_seconds"] = 0.0
-    built: list = []  # (seg_start, lay, arr, dev) — the corpus-put record
-    nxt = (
-        prepare(0, seg_starts[0])
-        if seg_starts and resident is None else None
-    )
+        seg_starts = list(range(0, max(len(data), 1), seg))
+        pool = (
+            _DaemonPool(1, thread_name_prefix="dgrep-feed")
+            if len(seg_starts) > 1 and resident is None else None
+        )
+        # Collect pool: sparse decode + host confirm of
+        # finished segments runs here, so confirms from different devices'
+        # segments overlap each other and the dispatch loop instead of
+        # serializing on it.  Mesh mode has one sharded stream — two workers
+        # cover decode/confirm pipelining; round-robin mode sizes to the
+        # device fan.  Single-segment scans collect inline (nothing to
+        # overlap).
+        from collections import deque as _deque
+
+        n_collect = 2 if use_mesh else min(4, max(1, len(devs)))
+        collect_pool = (
+            _DaemonPool(n_collect, thread_name_prefix="dgrep-collect")
+            if len(seg_starts) > 1 else None
+        )
+        collect_futs: _deque = _deque()
+        st["feed_wait_seconds"] = 0.0
+        built: list = []  # (seg_start, lay, arr, dev): the corpus-put record
+        nxt = (
+            prepare(0, seg_starts[0])
+            if seg_starts and resident is None else None
+        )
     try:
         for i, seg_start in enumerate(seg_starts):
             if resident is not None:
@@ -816,8 +863,9 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
             ctx = jax.default_device(dev) if dev is not None else nullcontext()
             # Dispatch the device scan; the sparse fetch (a 4-byte count
             # round-trip plus O(matches) coordinates — never the dense
-            # packed plane) happens in collect().
-            with ctx:
+            # packed plane) happens in collect().  Dispatch is async: the
+            # host waits here only on a compile (engine:wait).
+            with ctx, spans_mod.span("engine:wait", cat="engine"):
                 if use_fdr:
                     if use_mesh and ep_axis is not None:
                         # EP: same-plan banks shard their tables over
@@ -851,7 +899,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                                 interpret=interp_flag,
                                 fold_case=eng.ignore_case,
                             )
-                            words = w if words is None else words | w
+                            words = (w if words is None
+                                     else scan_jnp.or_words(words, w))
                     if eng._fdr_pairset is not None:
                         # a mixed set's 1-byte members: exact pairset
                         # kernel on device, OR'd into the candidate
@@ -865,14 +914,15 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                                 eng.mesh_axis, interpret=interp_flag,
                                 dev_tables=eng._pairset_device_tables(None),
                             )
-                            words = words | pw
+                            words = scan_jnp.or_words(words, pw)
                             psum_totals.append(ppt)
                         else:
-                            words = words | pallas_pairset.pairset_scan_words(
-                                arr, eng._fdr_pairset,
-                                dev_tables=eng._pairset_device_tables(dev),
-                                interpret=interp_flag,
-                            )
+                            words = scan_jnp.or_words(
+                                words, pallas_pairset.pairset_scan_words(
+                                    arr, eng._fdr_pairset,
+                                    dev_tables=eng._pairset_device_tables(dev),
+                                    interpret=interp_flag,
+                                ))
                     job = ("words", words, lay, seg_start, seg_len, dev)
                 elif use_pallas:
                     if use_pallas_sa:
@@ -975,7 +1025,11 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                 built.append((seg_start, lay, arr, dev))
             boundaries.extend((seg_start + lay.stripe_starts()).tolist())
             if collect_pool is not None:
-                collect_futs.append(collect_pool.submit(collect, job))
+                # the pool thread takes this thread's task context along,
+                # so its spans carry the task's tags
+                collect_futs.append(
+                    collect_pool.submit(spans_mod.carry(collect), job)
+                )
                 if len(collect_futs) >= max_inflight:
                     # bound resident result planes, like the old pending
                     # list: wait out the oldest in-flight collect.
@@ -986,7 +1040,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                 progress()  # one milestone per dispatched segment
             if nxt_future is not None:
                 t0 = _time.perf_counter()
-                nxt = nxt_future.result()
+                with spans_mod.span("engine:prep", cat="engine"):
+                    nxt = nxt_future.result()  # the feed's residual stall
                 st["feed_wait_seconds"] += _time.perf_counter() - t0
         while collect_futs:
             collect_futs.popleft().result()
@@ -1021,15 +1076,16 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     # FDR candidates were already confirmed offset-exactly in collect();
     # boundary lines (stripe/segment heads, where the filter's all-ones
     # seed under-reports) are restored by the stitching pass below.
-    stitched = lines_mod.stitch_lines(
-        device_lines, data, nl, boundaries, eng._host_line_matcher
-    )
+    with spans_mod.span("engine:stitch", cat="engine"):
+        stitched = lines_mod.stitch_lines(
+            device_lines, data, nl, boundaries, eng._host_line_matcher
+        )
+        lines_arr = np.asarray(sorted(stitched), dtype=np.int64)
     if use_mesh and psum_totals:
         # ICI-collective candidate tally across all segments — the
         # cross-check dryrun_multichip asserts against the host count.
         st["psum_candidates"] = sum(int(t) for t in psum_totals)
     st["scan_wall_seconds"] = _time.perf_counter() - t_wall0
     maybe_retune_fdr(eng, len(data))
-    lines_arr = np.asarray(sorted(stitched), dtype=np.int64)
     return ScanResult(lines_arr, int(lines_arr.size), len(data))
 

@@ -941,10 +941,22 @@ class GrepEngine:
         self._nl_local.stash = None
         # Span-pipeline telemetry (utils/spans.py): each scan becomes one
         # structured per-scan record — mode, bytes, duration, and the
-        # engine.stats counters (candidates, confirm seconds, fallback
-        # flags) that previously died with the process.  active() is one
-        # thread-local read when the pipeline is off.
-        t0 = _time_mod.perf_counter() if spans_mod.active() else None
+        # engine.stats counters (candidates, fallback flags) that
+        # previously died with the process — open while the scan runs, so
+        # the engine:* spans of the device path nest under it.  active()
+        # is one thread-local read when the pipeline is off.
+        with spans_mod.scan_span(self.mode) as rec:
+            res = self._scan_body(data, progress, corpus_key)
+            if rec is not None:
+                # after the EOL fix-up: the record's match count must
+                # equal the ScanResult the caller actually receives
+                rec.update(bytes=len(data), stats=self.stats,
+                           matches=res.n_matches)
+        return res
+
+    def _scan_body(self, data: bytes, progress, corpus_key) -> ScanResult:
+        """scan()'s body: the engine route, the nullable-'$' EOL fix-up,
+        and the cross-tier counters stamped into this thread's stats."""
         res = self._scan_impl(data, progress, corpus_key=corpus_key)
         # Nullable-at-'$' patterns (accept_eol at the line-start state,
         # e.g. '^$', '^ *$', 'x?$'): the empty match is valid at every
@@ -958,23 +970,12 @@ class GrepEngine:
         # anything past the last real line.  (Found by the round-4 wide
         # fuzz sweep, seed 3116.)
         if self.tables and any(bool(t.accept_eol[t.start]) for t in self.tables):
-            stash = getattr(self._nl_local, "stash", None)
-            nl = (
-                stash[1] if stash is not None and stash[0] == len(data)
-                # chunked scans stash per-piece indexes (wrong length) —
-                # recompute over the full buffer then
-                else lines_mod.newline_index(data)
-            )
-            n_lines = nl.size + (0 if not data or data.endswith(b"\n") else 1)
-            ml = res.matched_lines[res.matched_lines <= n_lines]
-            ml = np.union1d(ml, lines_mod.empty_line_numbers(data, nl))
-            res = ScanResult(
-                ml.astype(np.int64), int(ml.size), res.bytes_scanned
-            )
+            with spans_mod.span("engine:stitch", cat="engine"):
+                res = self._eol_fixup(data, res)
         cc = model_cache_counters()
         if cc:
             # cross-job model-cache telemetry rides engine.stats (and from
-            # there the scan_record piggyback readers): stamped only when
+            # there the scan_span piggyback readers): stamped only when
             # the cache has ever been touched, so cache-free processes
             # keep their exact stats shape
             self.stats.update(cc)
@@ -1003,15 +1004,22 @@ class GrepEngine:
             # fused follow tier (round 21): follow_fused_queries/wakes/
             # suffix_bytes_saved — separate dict so the =0 no-op holds
             self.stats.update(fol_mod.follow_fused_counters())
-        if t0 is not None:
-            # after the EOL fix-up: the record's match count must equal the
-            # ScanResult the caller actually receives
-            spans_mod.scan_record(
-                mode=self.mode, n_bytes=len(data),
-                seconds=_time_mod.perf_counter() - t0,
-                stats=self.stats, matches=res.n_matches,
-            )
         return res
+
+    def _eol_fixup(self, data: bytes, res: ScanResult) -> ScanResult:
+        """Nullable-at-'$' edges (see _scan_body): union in the empty
+        lines, drop anything past the last real line."""
+        stash = getattr(self._nl_local, "stash", None)
+        nl = (
+            stash[1] if stash is not None and stash[0] == len(data)
+            # chunked scans stash per-piece indexes (wrong length) —
+            # recompute over the full buffer then
+            else lines_mod.newline_index(data)
+        )
+        n_lines = nl.size + (0 if not data or data.endswith(b"\n") else 1)
+        ml = res.matched_lines[res.matched_lines <= n_lines]
+        ml = np.union1d(ml, lines_mod.empty_line_numbers(data, nl))
+        return ScanResult(ml.astype(np.int64), int(ml.size), res.bytes_scanned)
 
     def _scan_impl(self, data: bytes, progress=None, corpus_key=None) -> ScanResult:
         if self.mode == "re":
@@ -1406,74 +1414,83 @@ class GrepEngine:
         # returns the exact empty result WITHOUT opening the file; a
         # maybe (or no summary yet) scans, and a successful whole-file
         # scan publishes the summary for the next query.  The lookup is
-        # jax-free, like the corpus opt-in.
-        idx_req = self._index_requirements()
-        idx_key = None
-        idx_pub = False  # publish after a successful whole-file scan
-        if idx_req is not None:
-            from distributed_grep_tpu.index import summary as index_summary
+        # jax-free, like the corpus opt-in.  Both lookups are the scan's
+        # host preparation (engine:prep).
+        with spans_mod.span("engine:prep", cat="engine"):
+            idx_req = self._index_requirements()
+            idx_key = None
+            idx_pub = False  # publish after a successful whole-file scan
+            if idx_req is not None:
+                from distributed_grep_tpu.index import (
+                    summary as index_summary,
+                )
 
-            # lock-free routing gate: derive the key (realpath + stat)
-            # only when a lookup could answer or a publish could land —
-            # a summary-free one-shot process pays nothing per file
-            if index_summary.may_route() or self._index_publish_enabled():
-                idx_key = file_content_key(path)
-            if idx_key is not None:
-                summ = index_summary.lookup_summary(idx_key)
-                if summ is not None:
-                    if not idx_req.may_match(summ):
-                        return self._index_pruned(idx_key)
-                    index_summary.record_maybe()
-                    spans_mod.instant("index:maybe", cat="engine")
-                else:
-                    # single-chunk shards only — the corpus-cache regime:
-                    # multi-chunk cuts are content-dependent and the
-                    # target workload is many files under the chunk
-                    # target; and only when a reuse surface exists
-                    # (_index_publish_enabled — one-shot jobs skip the
-                    # build entirely)
-                    idx_pub = (
-                        0 < idx_key.n_bytes <= chunk_target
-                        and self._index_publish_enabled()
-                    )
+                # lock-free routing gate: derive the key (realpath + stat)
+                # only when a lookup could answer or a publish could land
+                # — a summary-free one-shot process pays nothing per file
+                if (index_summary.may_route()
+                        or self._index_publish_enabled()):
+                    idx_key = file_content_key(path)
+                if idx_key is not None:
+                    summ = index_summary.lookup_summary(idx_key)
+                    if summ is not None:
+                        if not idx_req.may_match(summ):
+                            return self._index_pruned(idx_key)
+                        index_summary.record_maybe()
+                        spans_mod.instant("index:maybe", cat="engine")
+                    else:
+                        # single-chunk shards only — the corpus-cache
+                        # regime: multi-chunk cuts are content-dependent
+                        # and the target workload is many files under the
+                        # chunk target; and only when a reuse surface
+                        # exists (_index_publish_enabled — one-shot jobs
+                        # skip the build entirely)
+                        idx_pub = (
+                            0 < idx_key.n_bytes <= chunk_target
+                            and self._index_publish_enabled()
+                        )
+
+            corpus_k = None
+            warm = None  # the corpus-cache entry that serves the whole file
+            if self._corpus_opt_in():
+                from distributed_grep_tpu.ops.layout import corpus_cache
+
+                # one fresh stat serves both tiers when the index already
+                # took it — key identity and validators must describe the
+                # same snapshot for the publish below to be sound
+                k = (idx_key if idx_key is not None
+                     else file_content_key(path))
+                # _small_route_cached: on a real accelerator a sub-
+                # device_min_bytes solo file host-routes and can never
+                # populate — skip the key/stat/lock work outright rather
+                # than pay a guaranteed-miss lookup per query (reads the
+                # CACHED backend flag only; jax-free)
+                if (
+                    k is not None and 0 < k.n_bytes <= chunk_target
+                    and not self._small_route_cached(k.n_bytes)
+                ):
+                    corpus_k = k
+                    ent = corpus_cache().lookup(k)
+                    if ent is not None and len(ent.data) == k.n_bytes:
+                        warm = ent
+
         idx_whole: bytes | None = None  # the whole keyed bytes, once in hand
-
-        corpus_k = None
-        if self._corpus_opt_in():
-            from distributed_grep_tpu.ops.layout import corpus_cache
-
-            # one fresh stat serves both tiers when the index already took
-            # it — key identity and validators must describe the same
-            # snapshot for the publish below to be sound
-            k = idx_key if idx_key is not None else file_content_key(path)
-            # _small_route_cached: on a real accelerator a sub-
-            # device_min_bytes solo file host-routes and can never
-            # populate — skip the key/stat/lock work outright rather
-            # than pay a guaranteed-miss lookup per query (reads the
-            # CACHED backend flag only; jax-free)
-            if (
-                k is not None and 0 < k.n_bytes <= chunk_target
-                and not self._small_route_cached(k.n_bytes)
-            ):
-                corpus_k = k
-                ent = corpus_cache().lookup(k)
-                if ent is not None and len(ent.data) == k.n_bytes:
-                    # warm: the revalidated entry's host bytes stand in
-                    # for the disk read (stat drift would have evicted
-                    # it) — the file is never opened.  Counted at the
-                    # cache (host-routed engines never reach the
-                    # resident_segments verdict in scan_device)
-                    corpus_cache().count_host_hit()
-                    scan_piece(ent.data, k)
-                    if idx_pub:
-                        # scan succeeded over the entry's (revalidated)
-                        # bytes: backfill the summary the index missed
-                        self._index_publish(k, ent.data)
-                    self.stats["end_offsets"] = end_offsets
-                    self.stats["read_wait_seconds"] = 0.0
-                    return ScanResult(
-                        np.asarray(matched, dtype=np.int64), n_matches, total
-                    )
+        if warm is not None:
+            # warm: the revalidated entry's host bytes stand in for the
+            # disk read (stat drift would have evicted it) — the file is
+            # never opened.  Counted at the cache (host-routed engines
+            # never reach the resident_segments verdict in scan_device)
+            corpus_cache().count_host_hit()
+            scan_piece(warm.data, corpus_k)
+            if idx_pub:
+                # scan succeeded over the entry's (revalidated) bytes:
+                # backfill the summary the index missed
+                self._index_publish(corpus_k, warm.data)
+            self.stats["end_offsets"] = end_offsets
+            self.stats["read_wait_seconds"] = 0.0
+            return ScanResult(
+                np.asarray(matched, dtype=np.int64), n_matches, total
+            )
 
         class _Ready:
             """Future-like wrapper for data already in hand (the first,
@@ -1505,12 +1522,14 @@ class GrepEngine:
         try:
             f = open(path, "rb")
             t0 = _time.perf_counter()
-            nxt = _Ready(f.read(chunk_target))
+            with spans_mod.span("engine:prep", cat="engine"):
+                nxt = _Ready(f.read(chunk_target))
             read_wait += _time.perf_counter() - t0  # the synchronous first
             # read is genuine stall: keep stats[read_wait_seconds] honest
             while True:
                 t0 = _time.perf_counter()
-                block = nxt.result()
+                with spans_mod.span("engine:prep", cat="engine"):
+                    block = nxt.result()  # the read-ahead's residual stall
                 read_wait += _time.perf_counter() - t0
                 if block:
                     # enqueue the NEXT read now; it overlaps this chunk's
@@ -1756,36 +1775,35 @@ class GrepEngine:
             shared by the cold flush and the warm-window path (which
             reuses the CACHED PackedBatch: demux tables and member blobs
             come from the entry, not a re-read + re-pack)."""
-            t0 = _time_mod.perf_counter()
-            t0_wall = _time_mod.time()
-            res = self.scan(batch.data, progress=progress,
-                            corpus_key=win_key)
-            if cache is not None and win_key is not None:
-                # record the demux tables + member blobs behind the
-                # entry this scan just published (no-op if it was not
-                # admitted) — what makes the next call's warm window
-                # possible without re-reading members
-                cache.attach_batch(win_key, batch)
-                if idx_on and index_summary.lookup_summary(win_key) is None:
-                    # window-level summary (packed-window pruning on the
-                    # warm path): built from batch.data, so boundary-
-                    # spanning trigrams only ADD bits — over-approximate,
-                    # never unsound.  Corpus-cache regimes only: without
-                    # a resident window there is no warm-window scan to
-                    # prune.
-                    self._index_publish(win_key, batch.data)
-            per_file = batch.demux(res.matched_lines)
+            # the scan:batch span is open over the inner scan, whose own
+            # scan:<mode> span nests under it
+            with spans_mod.span(
+                "scan:batch", cat="engine", mode=self.mode,
+                files=len(batch), bytes=len(batch.data),
+                fill_ratio=round(len(batch.data) / cap, 6),
+            ) as span_args:
+                res = self.scan(batch.data, progress=progress,
+                                corpus_key=win_key)
+                span_args["matches"] = res.n_matches
+                if cache is not None and win_key is not None:
+                    # record the demux tables + member blobs behind the
+                    # entry this scan just published (no-op if it was not
+                    # admitted) — what makes the next call's warm window
+                    # possible without re-reading members
+                    cache.attach_batch(win_key, batch)
+                    if (idx_on and index_summary.lookup_summary(win_key)
+                            is None):
+                        # window-level summary (packed-window pruning on
+                        # the warm path): built from batch.data, so
+                        # boundary-spanning trigrams only ADD bits —
+                        # over-approximate, never unsound.  Corpus-cache
+                        # regimes only: without a resident window there
+                        # is no warm-window scan to prune.
+                        self._index_publish(win_key, batch.data)
+                per_file = batch.demux(res.matched_lines)
             bstats["batched_files"] += len(batch)
             bstats["batch_dispatches"] += 1
             bstats["fill_sum"] += len(batch.data) / cap
-            if spans_mod.active():
-                spans_mod.complete(
-                    "scan:batch", t0_wall,
-                    _time_mod.perf_counter() - t0, cat="engine",
-                    mode=self.mode, files=len(batch),
-                    bytes=len(batch.data), matches=res.n_matches,
-                    fill_ratio=round(len(batch.data) / cap, 6),
-                )
             # member_blobs(): as-stored on a fresh pack, transient
             # slices of batch.data on a cache-slimmed warm window
             for name, blob, lines in zip(names, batch.member_blobs(),
@@ -1842,20 +1860,23 @@ class GrepEngine:
         while i < len(items):
             name, data = items[i]
             is_blob = isinstance(data, (bytes, bytearray, memoryview))
-            fk = None
-            if (use_corpus or idx_on) and not is_blob:
-                fk = file_content_key(data)
-            if use_corpus and not is_blob and fk is not None \
-                    and packer is not None:
-                stored = cache.window_for(fk)
-                keys = (
-                    match_window(i, stored)
-                    if stored is not None else None
-                )
+            fk = keys = wk = ent = summ = None
+            # the lookups (content key, warm window, shard index) are the
+            # item's host preparation; scans and emits run outside
+            with spans_mod.span("engine:prep", cat="engine"):
+                if (use_corpus or idx_on) and not is_blob:
+                    fk = file_content_key(data)
+                if use_corpus and not is_blob and fk is not None \
+                        and packer is not None:
+                    stored = cache.window_for(fk)
+                    keys = (
+                        match_window(i, stored)
+                        if stored is not None else None
+                    )
                 if keys is not None:
                     wk = batch_content_key(keys)
                     ent = cache.lookup(wk)
-                    if (
+                    if not (
                         ent is not None and ent.batch is not None
                         # the ENGINE's cap governs warm content too:
                         # a window packed under a larger budget is
@@ -1865,62 +1886,58 @@ class GrepEngine:
                         # oversized entry ages out via LRU)
                         and len(ent.batch.data) <= cap
                     ):
-                        wsum = None
-                        if idx_on:
-                            wsum = (
-                                ent.summary
-                                if ent.summary is not None
-                                else index_summary.lookup_summary(wk)
-                            )
-                        if wsum is not None and not idx_req.may_match(wsum):
-                            # whole warm window pruned: members emit their
-                            # REAL cached blobs with the (exact) empty
-                            # result the summary proves — sound for every
-                            # consumer incl. invert, and no union scan is
-                            # dispatched
-                            flush()
-                            index_summary.record_prune(wk.n_bytes)
-                            spans_mod.instant("index:prune", cat="engine",
-                                              bytes=wk.n_bytes)
-                            names_w = [nm for nm, _ in
-                                       items[i:i + len(keys)]]
-                            for nm, blob in zip(names_w,
-                                                ent.batch.member_blobs()):
-                                handle(nm, blob, ScanResult(
-                                    np.zeros(0, dtype=np.int64), 0,
-                                    len(blob),
-                                ))
-                            i += len(keys)
-                            continue
-                        if wsum is not None:
-                            # consulted and could not rule the query
-                            # out: the warm-window scan is a maybe (the
-                            # counter the dense-regime telemetry reads)
-                            index_summary.record_maybe()
-                        flush()  # order-preserving, like a solo input
-                        cache.count_host_hit()
-                        scan_packed(
-                            ent.batch,
-                            [nm for nm, _ in items[i:i + len(keys)]],
-                            wk,
-                        )
-                        if idx_pub_ok:
-                            # backfill per-MEMBER summaries from the
-                            # cached blobs (the warm path never iterates
-                            # members, so a corpus-warm daemon would
-                            # otherwise starve the planner of the
-                            # per-file summaries it prunes with)
-                            for mk, blob in zip(
-                                keys, ent.batch.member_blobs()
-                            ):
-                                if index_summary.lookup_summary(mk) is None:
-                                    self._index_publish(mk, blob)
-                        i += len(keys)
-                        continue
+                        ent = None
+                if ent is not None and idx_on:
+                    summ = (
+                        ent.summary if ent.summary is not None
+                        else index_summary.lookup_summary(wk)
+                    )
+                elif ent is None and idx_on and fk is not None:
+                    summ = index_summary.lookup_summary(fk)
+            if ent is not None:
+                if summ is not None and not idx_req.may_match(summ):
+                    # whole warm window pruned: members emit their
+                    # REAL cached blobs with the (exact) empty
+                    # result the summary proves — sound for every
+                    # consumer incl. invert, and no union scan is
+                    # dispatched
+                    flush()
+                    index_summary.record_prune(wk.n_bytes)
+                    spans_mod.instant("index:prune", cat="engine",
+                                      bytes=wk.n_bytes)
+                    names_w = [nm for nm, _ in items[i:i + len(keys)]]
+                    for nm, blob in zip(names_w, ent.batch.member_blobs()):
+                        handle(nm, blob, ScanResult(
+                            np.zeros(0, dtype=np.int64), 0, len(blob),
+                        ))
+                    i += len(keys)
+                    continue
+                if summ is not None:
+                    # consulted and could not rule the query
+                    # out: the warm-window scan is a maybe (the
+                    # counter the dense-regime telemetry reads)
+                    index_summary.record_maybe()
+                flush()  # order-preserving, like a solo input
+                cache.count_host_hit()
+                scan_packed(
+                    ent.batch,
+                    [nm for nm, _ in items[i:i + len(keys)]],
+                    wk,
+                )
+                if idx_pub_ok:
+                    # backfill per-MEMBER summaries from the
+                    # cached blobs (the warm path never iterates
+                    # members, so a corpus-warm daemon would
+                    # otherwise starve the planner of the
+                    # per-file summaries it prunes with)
+                    for mk, blob in zip(keys, ent.batch.member_blobs()):
+                        if index_summary.lookup_summary(mk) is None:
+                            self._index_publish(mk, blob)
+                i += len(keys)
+                continue
             i += 1
             idx_missing = False  # publish this member after its scan
             if idx_on and fk is not None:
-                summ = index_summary.lookup_summary(fk)
                 if summ is None:
                     idx_missing = idx_pub_ok
                 elif not idx_req.may_match(summ):
@@ -1939,25 +1956,26 @@ class GrepEngine:
                     # still exact, the index just saves nothing here
                 else:
                     index_summary.record_maybe()
-            if not is_blob:
-                ent = (
-                    cache.lookup(fk)
-                    if cache is not None and fk is not None else None
-                )
-                if ent is not None and len(ent.data) == fk.n_bytes:
-                    data = ent.data  # warm host bytes: no disk read
-                    cache.count_host_hit()
-                else:
-                    t_r = _time_mod.perf_counter()
-                    with open(_os.fspath(data), "rb") as f:
-                        data = f.read()
-                    read_wait += _time_mod.perf_counter() - t_r
-                    if fk is not None and (
-                        len(data) != fk.n_bytes
-                        or file_content_key(items[i - 1][1]) != fk
-                    ):
-                        fk = None  # changed between stat and read: uncached
-            data = bytes(data)
+            with spans_mod.span("engine:prep", cat="engine"):
+                if not is_blob:
+                    ent = (
+                        cache.lookup(fk)
+                        if cache is not None and fk is not None else None
+                    )
+                    if ent is not None and len(ent.data) == fk.n_bytes:
+                        data = ent.data  # warm host bytes: no disk read
+                        cache.count_host_hit()
+                    else:
+                        t_r = _time_mod.perf_counter()
+                        with open(_os.fspath(data), "rb") as f:
+                            data = f.read()
+                        read_wait += _time_mod.perf_counter() - t_r
+                        if fk is not None and (
+                            len(data) != fk.n_bytes
+                            or file_content_key(items[i - 1][1]) != fk
+                        ):
+                            fk = None  # changed between stat and read
+                data = bytes(data)
             small = len(data) < self.device_min_bytes
             if packer is None or not small or packed_size(data) > cap:
                 flush()  # order-preserving: pending smalls go first

@@ -192,16 +192,31 @@ def shift_and_scan(data_cl: np.ndarray, model: ShiftAndModel) -> jnp.ndarray:
 # (a few KB for realistic match densities).
 
 
+# Each XLA-side step of a scan is a jitted function of its own with a
+# named scope, so a profiler trace names it (its XLA module and its ops'
+# metadata) instead of an anonymous eager op.
+
+
 @jax.jit
 def count_nonzero_bytes(packed: jnp.ndarray) -> jnp.ndarray:
-    return jnp.count_nonzero(packed)
+    with jax.named_scope("count_nonzero_bytes"):
+        return jnp.count_nonzero(packed)
 
 
 @partial(jax.jit, static_argnames=("k",))
 def gather_nonzero_bytes(packed: jnp.ndarray, k: int):
-    flat = packed.reshape(-1)
-    idx = jnp.nonzero(flat, size=k, fill_value=0)[0]
-    return idx, flat[idx]
+    with jax.named_scope("gather_nonzero_bytes"):
+        flat = packed.reshape(-1)
+        idx = jnp.nonzero(flat, size=k, fill_value=0)[0]
+        return idx, flat[idx]
+
+
+@jax.jit
+def or_words(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Two candidate-word planes OR'd on device (FDR banks, the pairset
+    sidecar)."""
+    with jax.named_scope("or_banks"):
+        return a | b
 
 
 def sparse_nonzero(packed_dev) -> tuple[np.ndarray, np.ndarray]:

@@ -6,9 +6,10 @@ kernel family ran, was it pruned/fused/cache-warm, where did the time go"
 — but only as a raw ``events.jsonl`` an operator had to replay in
 Perfetto.  ``assemble()`` folds one job's event log (plus the job
 record's planning tallies) into ONE JSON document: engine modes with
-bytes/seconds/matches, host-vs-device routing, index prune counts, fused
-attempts, model/corpus cache verdicts, per-stage walls, and task/attempt
-accounting.  Served as ``GET /jobs/<id>/explain`` by the service daemon
+bytes/seconds/matches/compiles, host-vs-device routing, index prune
+counts, fused attempts, model/corpus cache verdicts, per-stage walls with
+their self seconds (a span's seconds less its children's, by the records'
+``parent`` links), and task/attempt accounting.  Served as ``GET /jobs/<id>/explain`` by the service daemon
 and rendered by ``dgrep explain`` (and ``dgrep submit --explain``).
 
 Pure Python, no ops imports — the daemon control plane assembles reports
@@ -72,20 +73,30 @@ def summarize_events(events: list[dict]) -> dict:
     tasks = {"map_assigns": 0, "reduce_assigns": 0, "timeouts": 0,
              "map_commits": 0, "reduce_commits": 0}
     follow = {"solo_wakes": 0, "fused_wakes": 0, "records": 0}
+    # seconds of the spans whose parent is each span name: a row's self
+    # seconds are its seconds minus those of its direct children
+    child_seconds: dict[str, float] = {}
     for r in events:
         name = r.get("name", "")
         t = r.get("t")
         if t == "span":
             args = r.get("args") or {}
+            parent = r.get("parent")
+            if parent:
+                child_seconds[parent] = (child_seconds.get(parent, 0.0)
+                                         + float(r.get("dur", 0.0)))
             if name.startswith("scan:"):
                 row = modes.setdefault(
                     name[len("scan:"):],
-                    {"scans": 0, "bytes": 0, "seconds": 0.0, "matches": 0},
+                    {"scans": 0, "bytes": 0, "seconds": 0.0, "matches": 0,
+                     "compiles": 0},
                 )
                 row["scans"] += 1
                 row["bytes"] += int(args.get("bytes", 0))
                 row["seconds"] += float(r.get("dur", 0.0))
                 row["matches"] += int(args.get("matches", 0))
+                # backend compiles the scans waited on (scan_span)
+                row["compiles"] += int(args.get("compiles", 0))
                 # which engine ran the scan (utils/spans.scan_record):
                 # pallas / xla on the device, host for the host scanners
                 kernel = args.get("kernel")
@@ -165,9 +176,13 @@ def summarize_events(events: list[dict]) -> dict:
                 tasks["map_commits"] += 1
             elif name == "reduce_committed":
                 tasks["reduce_commits"] += 1
-    for row in modes.values():
+    for mode, row in modes.items():
+        row["self_seconds"] = round(
+            row["seconds"] - child_seconds.get(f"scan:{mode}", 0.0), 6)
         row["seconds"] = round(row["seconds"], 6)
-    for row in stages.values():
+    for name, row in stages.items():
+        row["self_seconds"] = round(
+            row["seconds"] - child_seconds.get(name, 0.0), 6)
         row["seconds"] = round(row["seconds"], 6)
     out: dict = {"modes": modes, "stages": stages, "tasks": tasks}
     out.update(routing)  # model_cache / corpus_cache, present when seen

@@ -420,6 +420,14 @@ class Scheduler:
 
         if resume_entries:
             self._replay(resume_entries)
+        # every map task is runnable from the start; reduces once the
+        # map phase is done (here only by replay, or with no map task)
+        now = time.time()
+        for t in self.map_tasks:
+            t.runnable_at = now
+        if self._map_phase_done_locked():
+            for rt in self.reduce_tasks:
+                rt.runnable_at = now
 
         self._sweeper = threading.Thread(
             target=self._sweep_loop, name="failure-detector", daemon=True
@@ -539,6 +547,22 @@ class Scheduler:
             "t": "instant", "name": name, "cat": "sched",
             "ts": time.time(), **({"args": args} if args else {}),
         })
+
+    def _stage_wait(self, kind: str, task) -> None:
+        """Stage the task's ``<kind>:wait`` span — from runnable to this
+        assignment, the wait for a worker slot — the way _event stages
+        (call under the lock; written by _flush_events after release)."""
+        if self.event_log is None or not task.runnable_at:
+            return
+        now = time.time()
+        self._pending_events.append({
+            "t": "span",
+            "name": "map:wait" if kind == "map" else "reduce:wait",
+            "cat": "sched", "ts": task.runnable_at,
+            "dur": max(0.0, now - task.runnable_at), "parent": None,
+            "args": {"task": task.task_id, "attempt": task.attempts},
+        })
+        task.runnable_at = 0.0
 
     def _flush_events(self) -> None:
         """Write staged coordinator events outside the scheduler lock.
@@ -797,6 +821,7 @@ class Scheduler:
                     task.fused_claim = False  # normal assign: chargeable
                     self.metrics.inc("map_assigned")
                     self._worker_seen(worker_id, task=f"map:{tid}")
+                    self._stage_wait("map", task)
                     self._event("assign_map", task=tid, worker=worker_id,
                                 attempt=task.attempts, file=task.file)
                     log.debug("assign map task %d (%s) -> worker %d", tid, task.file, worker_id)
@@ -825,6 +850,7 @@ class Scheduler:
                     task.stamped = False  # see the map branch above
                     self.metrics.inc("reduce_assigned")
                     self._worker_seen(worker_id, task=f"reduce:{tid}")
+                    self._stage_wait("reduce", task)
                     self._event("assign_reduce", task=tid, worker=worker_id,
                                 attempt=task.attempts)
                     log.debug("assign reduce task %d -> worker %d", tid, worker_id)
@@ -881,6 +907,7 @@ class Scheduler:
                 self.metrics.inc("map_assigned")
                 self.metrics.inc("fused_assigned")
                 self._worker_seen(worker_id, task=f"map:{task_id}")
+                self._stage_wait("map", task)
                 self._event("assign_map", task=task_id, worker=worker_id,
                             attempt=task.attempts, file=task.file,
                             fused=True)
@@ -959,6 +986,12 @@ class Scheduler:
             task.peer = peer
             self._register_map_outputs(args.task_id, parts)
             self.metrics.inc("map_completed")
+            if self._map_phase_done_locked():
+                # the map phase completes: the reduce tasks become runnable
+                now = time.time()
+                for rt in self.reduce_tasks:
+                    if rt.state is TaskState.UNASSIGNED:
+                        rt.runnable_at = now
             if self._map_phase_done_locked() and not self._phase_observed:
                 self._phase_observed = True
                 now = time.monotonic()
@@ -1168,6 +1201,7 @@ class Scheduler:
         task.stamped = False
         self._maps_completed -= 1
         self._map_queue.append(tid)
+        task.runnable_at = time.time()
         self.metrics.inc("maps_lost_output")
         self.metrics.inc("map_retries")
         self.metrics.inc("tasks_requeued")
@@ -1208,6 +1242,7 @@ class Scheduler:
             rt.worker = -1
             rt.stamped = False
             self._reduce_queue.append(args.task_id)
+            rt.runnable_at = time.time()
             self.metrics.inc("reduce_retries")
             self.metrics.inc("tasks_requeued")
             _C_REQUEUED.inc()
@@ -1300,6 +1335,7 @@ class Scheduler:
                             failed_workers.append(task.worker)
                         task.state = TaskState.UNASSIGNED
                         self._map_queue.append(task.task_id)
+                        task.runnable_at = time.time()
                         requeued = True
                         self.metrics.inc("map_retries")
                         self.metrics.inc("tasks_requeued")
@@ -1322,6 +1358,7 @@ class Scheduler:
                             failed_workers.append(task.worker)
                         task.state = TaskState.UNASSIGNED
                         self._reduce_queue.append(task.task_id)
+                        task.runnable_at = time.time()
                         requeued = True
                         self.metrics.inc("reduce_retries")
                         self.metrics.inc("tasks_requeued")

@@ -68,6 +68,9 @@ class MapTask:
     # None on relay commits (bytes on the coordinator, pre-peer behavior).
     # Cleared when a lost-output report re-enqueues the task.
     peer: dict | None = None
+    # Wall clock at which the task last became runnable (enqueue or
+    # re-enqueue); 0 once assigned — the start of its map:wait span.
+    runnable_at: float = 0.0
 
     def heartbeat(self, grace_s: float = 0.0) -> None:
         self.timestamp = time.monotonic()
@@ -86,6 +89,9 @@ class ReduceTask:
     # Intermediate files registered as map tasks commit; reducers stream these
     # in arrival order (the pipelined shuffle, coordinator.go:159-174).
     task_files: list[str] = field(default_factory=list)
+    # Wall clock at which the task last became runnable (map-phase
+    # completion or re-enqueue); 0 once assigned — see MapTask.
+    runnable_at: float = 0.0
 
     def heartbeat(self, grace_s: float = 0.0) -> None:
         self.timestamp = time.monotonic()

@@ -30,7 +30,6 @@ from distributed_grep_tpu.runtime.extsort import ExternalReducer
 from distributed_grep_tpu.runtime.transport import Transport
 from distributed_grep_tpu.utils import metrics as metrics_mod
 from distributed_grep_tpu.utils import spans as spans_mod
-from distributed_grep_tpu.utils import trace
 from distributed_grep_tpu.utils.logging import get_logger
 from distributed_grep_tpu.utils.metrics import Metrics
 
@@ -370,6 +369,22 @@ class WorkerLoop:
             with spans_mod.span(f"{kind}:commit", cat=kind):
                 publish(kind, task_id, attempt, payload)
 
+    def _region(self, prefix: str, task_id: int) -> str:
+        """A task phase's profiler region name: ``prefix`` (what the
+        benchmark's trace reduction keys on), then job and task — task
+        ids alone collide across a service's tenants."""
+        return f"{prefix}:{self.job_id}:{task_id}"
+
+    def _read_span(self, a: rpc.AssignTaskReply, **args):
+        return spans_mod.span("map:read", cat="map",
+                              annotation=self._region("map_read", a.task_id),
+                              file=a.filename, **args)
+
+    def _compute_span(self, task_id: int, **args):
+        return spans_mod.span("map:compute", cat="map",
+                              annotation=self._region("map_compute", task_id),
+                              **args)
+
     def _task_ctx(self, kind: str, task_id: int, attempt: str):
         """The span pipeline's ambient task context for one attempt — a
         nullcontext when the pipeline is off, so every emit site below
@@ -559,18 +574,13 @@ class WorkerLoop:
                     and getattr(self.app, "map_batch_paths", False)
                 )
                 with download_guard(), \
-                        trace.annotate(f"map_read:{a.task_id}"), \
-                        spans_mod.span("map:read", cat="map",
-                                       file=a.filename,
-                                       files=len(a.filenames)):
+                        self._read_span(a, files=len(a.filenames)):
                     blobs, n_bytes = self._read_members(
                         a.filenames, want_paths=batch_paths
                     )
                 self._fault("after_map_read")
                 with self.metrics.timer("map_compute"), \
-                        trace.annotate(f"map_compute:{a.task_id}"), \
-                        spans_mod.span("map:compute", cat="map"), \
-                        compute_guard():
+                        self._compute_span(a.task_id), compute_guard():
                     if batch_fn is not None:
                         records = batch_fn(blobs)
                     else:
@@ -582,32 +592,24 @@ class WorkerLoop:
             elif use_path:
                 import os
 
-                with download_guard(), \
-                        trace.annotate(f"map_read:{a.task_id}"), \
-                        spans_mod.span("map:read", cat="map", file=a.filename):
+                with download_guard(), self._read_span(a):
                     path, is_temp = self.transport.read_input_path(a.filename)
                 try:
                     self._fault("after_map_read")
                     n_bytes = os.path.getsize(path)
                     with self.metrics.timer("map_compute"), \
-                            trace.annotate(f"map_compute:{a.task_id}"), \
-                            spans_mod.span("map:compute", cat="map"), \
-                            compute_guard():
+                            self._compute_span(a.task_id), compute_guard():
                         records = self.app.map_path_fn(a.filename, str(path))
                 finally:
                     if is_temp:
                         os.unlink(path)
                 self.metrics.record_scan(n_bytes, time.perf_counter() - t0)
             else:
-                with download_guard(), \
-                        trace.annotate(f"map_read:{a.task_id}"), \
-                        spans_mod.span("map:read", cat="map", file=a.filename):
+                with download_guard(), self._read_span(a):
                     contents = self.transport.read_input(a.filename)
                 self._fault("after_map_read")
                 with self.metrics.timer("map_compute"), \
-                        trace.annotate(f"map_compute:{a.task_id}"), \
-                        spans_mod.span("map:compute", cat="map"), \
-                        compute_guard():
+                        self._compute_span(a.task_id), compute_guard():
                     records = self.app.map_fn(a.filename, contents)
                 self.metrics.record_scan(len(contents), time.perf_counter() - t0)
         finally:
@@ -641,7 +643,8 @@ class WorkerLoop:
             return self._pumping("map", a.task_id, pump_s)
 
         with shuffle_guard(), spans_mod.span("map:shuffle", cat="map"):
-            buckets = shuffle.bucketize(records, a.n_reduce)
+            with spans_mod.span("shuffle:build", cat="map"):
+                buckets = shuffle.bucketize(records, a.n_reduce)
             self._fault("before_map_commit")
             produced, peer_meta = self._write_map_outputs(a.task_id, buckets)
         payload: dict = {"parts": produced}
@@ -745,10 +748,7 @@ class WorkerLoop:
         # record_scan/map_task_total telemetry must include the read leg
         # or fused gbps reads systematically higher than solo's
         with self._task_ctx("map", a.task_id, attempt0):
-            with fused_pump(), \
-                    trace.annotate(f"map_read:{a.task_id}"), \
-                    spans_mod.span("map:read", cat="map", file=a.filename,
-                                   files=len(names)):
+            with fused_pump(), self._read_span(a, files=len(names)):
                 items, n_bytes = self._read_members(names, want_paths)
             self._fault("after_map_read")
             has_progress = self.app.set_progress(progress)
@@ -756,9 +756,8 @@ class WorkerLoop:
             try:
                 if self.app.map_fused_fn is not None:
                     with self.metrics.timer("map_compute"), \
-                            trace.annotate(f"map_compute:{a.task_id}"), \
-                            spans_mod.span("map:compute", cat="map",
-                                           fused=len(participants)):
+                            self._compute_span(a.task_id,
+                                               fused=len(participants)):
                         records_per = self.app.map_fused_fn(
                             items, participants
                         )
@@ -899,7 +898,8 @@ class WorkerLoop:
         )
         with ctx:
             with spans_mod.span("map:shuffle", cat="map"):
-                buckets = shuffle.bucketize(records, part["n_reduce"])
+                with spans_mod.span("shuffle:build", cat="map"):
+                    buckets = shuffle.bucketize(records, part["n_reduce"])
                 self._fault("before_map_commit")
                 produced, peer_meta = self._write_map_outputs(tid, buckets)
             payload: dict = {"parts": produced}
@@ -1031,7 +1031,9 @@ class WorkerLoop:
                 "reduce:shuffle", t_shuffle, time.time() - t_shuffle,
                 cat="reduce", files=files_processed,
             )
-            with spans_mod.span("reduce:compute", cat="reduce"):
+            with spans_mod.span(
+                    "reduce:compute", cat="reduce",
+                    annotation=self._region("reduce_compute", a.task_id)):
                 self._write_reduce_output(a, chunks(), progress_stride)
         finally:
             if sink.spill_count:
@@ -1124,7 +1126,6 @@ class WorkerLoop:
             # (native formatter); str chunks encode utf-8/surrogateescape —
             # exactly what the old text-mode writer did per write.
             with self.metrics.timer("reduce_compute"), \
-                    trace.annotate(f"reduce_compute:{a.task_id}"), \
                     os.fdopen(fd, "wb") as out:
                 for i, chunk in enumerate(chunks):
                     out.write(

@@ -6,8 +6,9 @@ The reference's only observability is fmt.Printf progress lines
 across process boundaries: workers collected rich `Metrics` and per-scan
 `engine.stats` that died with the process.  Here every task attempt emits
 structured spans — read → kernel scan → confirm/stitch → shuffle → commit —
-tagged with (job, task, attempt, worker) ids, plus instant events for
-degrade/fallback transitions.  Workers buffer records in a bounded
+tagged with (job, task, attempt, worker) ids and linked to the span open
+around them (``parent``), plus instant events for degrade/fallback
+transitions.  Workers buffer records in a bounded
 `SpanBuffer` and flush them piggybacked on the existing Heartbeat /
 TaskFinished RPCs (optional fields elided from the wire when empty, so old
 peers interop); the coordinator persists everything as `events.jsonl` in
@@ -34,6 +35,7 @@ from contextlib import contextmanager
 from pathlib import Path
 
 from distributed_grep_tpu.utils import event_audit, lockdep
+from distributed_grep_tpu.utils import trace as trace_mod
 
 _ENV_VAR = "DGREP_SPANS"
 
@@ -144,6 +146,37 @@ def active() -> bool:
     return getattr(_tls, "ctx", None) is not None
 
 
+def carry(fn):
+    """``fn`` bound to the calling thread's task context and open scan
+    counter — for work handed to a pool thread, which has neither (its
+    spans would otherwise be dropped).  The pool thread's own span stack
+    stays empty: its records' parent is what is open on THAT thread."""
+    ctx = getattr(_tls, "ctx", None)
+    if ctx is None:
+        return fn
+    buffer, tags = ctx
+    cell = getattr(_tls, "compiles", None)
+
+    def run(*a, **kw):
+        prev = getattr(_tls, "compiles", None)
+        _tls.compiles = cell
+        try:
+            with task_context(buffer, **tags):
+                return fn(*a, **kw)
+        finally:
+            _tls.compiles = prev
+
+    return run
+
+
+def _open_spans() -> list:
+    """This thread's stack of open span names (innermost last)."""
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack
+
+
 def _emit(rec: dict) -> None:
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
@@ -152,29 +185,50 @@ def _emit(rec: dict) -> None:
     buffer.add({**tags, **rec})
 
 
-def complete(name: str, ts: float, dur: float, cat: str = "task",
-             **args) -> None:
-    """Emit an already-timed span (ts = wall-clock start, dur seconds)."""
-    if not active():
-        return
-    rec: dict = {"t": "span", "name": name, "cat": cat,
-                 "ts": ts, "dur": dur}
+def _emit_span(name: str, cat: str, ts: float, dur: float,
+               parent: str | None, args: dict) -> None:
+    rec: dict = {"t": "span", "name": name, "cat": cat, "ts": ts,
+                 "dur": dur, "parent": parent}
     if args:
         rec["args"] = args
     _emit(rec)
 
 
-@contextmanager
-def span(name: str, cat: str = "task", **args):
-    """Timed region on the current task's row; no-op outside a context."""
+def complete(name: str, ts: float, dur: float, cat: str = "task",
+             **args) -> None:
+    """Emit an already-timed span (ts = wall-clock start, dur seconds);
+    its parent is the innermost span open on this thread now."""
     if not active():
-        yield
         return
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        complete(name, t0, time.time() - t0, cat=cat, **args)
+    stack = _open_spans()
+    _emit_span(name, cat, ts, dur, stack[-1] if stack else None, args)
+
+
+@contextmanager
+def span(name: str, cat: str = "task", annotation: str | None = None,
+         **args):
+    """Timed region on the current task's row; no-op outside a context.
+    The record's ``parent`` is the innermost span open on this thread
+    when it started.  Yields the record's args dict (a throwaway one
+    outside a context), so the body can add what it learns.
+
+    With DGREP_TRACE_DIR set the region is also a jax.profiler
+    TraceAnnotation named ``annotation`` (default: the span's name), so
+    the span and the host region cover one interval on the profiler's
+    timeline."""
+    with trace_mod.annotate(annotation or name):
+        if not active():
+            yield {}
+            return
+        stack = _open_spans()
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        t0 = time.time()
+        try:
+            yield args
+        finally:
+            stack.pop()
+            _emit_span(name, cat, t0, time.time() - t0, parent, args)
 
 
 def instant(name: str, cat: str = "task", **args) -> None:
@@ -189,38 +243,65 @@ def instant(name: str, cat: str = "task", **args) -> None:
 
 # Engine stats keys promoted into scan telemetry records when present
 # (ops/engine.py / ops/device_scan.py populate them per scan).
-_SCAN_STAT_KEYS = (
-    "candidates", "confirm_seconds", "end_offsets",
-    "feed_wait_seconds", "read_wait_seconds",
-)
+_SCAN_STAT_KEYS = ("candidates", "end_offsets")
 
 
-def scan_record(mode: str, n_bytes: int, seconds: float,
-                stats: dict | None = None, matches: int | None = None) -> None:
-    """Per-scan engine telemetry: one span named scan:<mode> whose args are
-    the structured form of `engine.stats` (candidates, confirm seconds,
-    kernel route).  The engine calls this after every scan(); it no-ops
-    unless the scanning thread is inside a task_context."""
-    if not active():
-        return
-    st = stats or {}
+def count_compile() -> None:
+    """One backend compile, counted into the scan span open on this
+    thread (or carried into it) — ops/device_scan's jax.monitoring
+    listener calls this; a no-op outside a scan."""
+    cell = getattr(_tls, "compiles", None)
+    if cell is not None:
+        cell.append(1)  # list.append: atomic across collect threads
+
+
+@contextmanager
+def scan_span(mode: str):
+    """Per-scan engine telemetry: one span named scan:<mode>, open while
+    the scan runs so the ``engine:*`` spans inside nest under it.  Yields
+    a dict the engine fills with ``bytes``, ``stats`` and ``matches`` once
+    the result is known (None outside a task_context); the record's args
+    are the structured form of `engine.stats` (candidates, kernel route)
+    plus ``compiles``, the backend compiles the scan waited on.  A scan
+    that raises records nothing."""
+    name = f"scan:{mode}"
+    with trace_mod.annotate(name):
+        if not active():
+            yield None
+            return
+        stack = _open_spans()
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        prev = getattr(_tls, "compiles", None)
+        cell: list = []
+        _tls.compiles = cell
+        done: dict = {}
+        t0 = time.time()
+        try:
+            yield done
+        finally:
+            stack.pop()
+            _tls.compiles = prev
+        dur = time.time() - t0
+    if prev is not None:
+        prev.extend(cell)  # a scan nested in a scan waited on these too
+    st = done.get("stats") or {}
     args: dict = {
         "mode": mode,
-        "bytes": int(n_bytes),
+        "bytes": int(done.get("bytes", 0)),
         # always present: which engine actually ran — "pallas"/"xla" from
         # the device scan (ops/device_scan), "host" for the host scanners
         "kernel": st.get("kernel", "host"),
         "interpret": bool(st.get("interpret", False)),
     }
-    if matches is not None:
-        args["matches"] = int(matches)
+    if done.get("matches") is not None:
+        args["matches"] = int(done["matches"])
     for k in _SCAN_STAT_KEYS:
         if k in st:
-            v = st[k]
-            args[k] = round(v, 6) if isinstance(v, float) else v
-    now = time.time()
+            args[k] = st[k]
+    args["compiles"] = len(cell)
     _emit({"t": "span", "name": f"scan:{mode}", "cat": "engine",
-           "ts": now - seconds, "dur": seconds, "args": args})
+           "ts": t0, "dur": dur, "parent": parent, "args": args})
 
 
 def split_by_job(recs: list[dict], default: str = "") -> dict[str, list[dict]]:

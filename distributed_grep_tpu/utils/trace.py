@@ -15,10 +15,10 @@ so the hot paths pay nothing in production.
 This module covers the DEVICE side of the observability story; the
 cross-process control-plane side is utils/spans.py (worker→coordinator
 span shipping, events.jsonl, `dgrep trace-export`).  Both render into the
-same Perfetto/TensorBoard viewers, and `annotate`'s region names match the
-span names the worker emits (map:read/map:compute per task id), so the
-exported span rows line up with the jax.profiler device rows when a run
-enables both DGREP_TRACE_DIR and the span pipeline.
+same Perfetto/TensorBoard viewers: a context-managed span
+(`spans.span`, `spans.scan_span`) opens an `annotate` region over the same
+interval, so the exported span rows line up with the jax.profiler device
+rows when a run enables both DGREP_TRACE_DIR and the span pipeline.
 """
 
 from __future__ import annotations
@@ -77,19 +77,3 @@ def annotate(name: str):
     except Exception:  # pragma: no cover
         return nullcontext()
     return jax.profiler.TraceAnnotation(name)
-
-
-@contextmanager
-def step_trace(name: str, step: int):
-    """StepTraceAnnotation: groups device ops under a numbered step, the
-    idiom the profiler uses to delimit training steps — here, scan passes."""
-    if not enabled():
-        yield
-        return
-    try:
-        import jax
-    except Exception:  # pragma: no cover
-        yield
-        return
-    with jax.profiler.StepTraceAnnotation(name, step_num=step):
-        yield

@@ -380,6 +380,7 @@ def test_summarize_events_unit():
     agg = explain_mod.summarize_events(events)
     assert agg["modes"]["fdr"] == {
         "scans": 1, "bytes": 100, "seconds": 0.5, "matches": 3,
+        "compiles": 0, "self_seconds": 0.5,
         "kernels": {"pallas": 1}, "kernel_bytes": {"pallas": 100}}
     assert agg["model_cache"]["hits"] == 2
     assert agg["corpus_cache"]["misses"] == 1

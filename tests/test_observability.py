@@ -83,6 +83,59 @@ def test_span_buffer_bounded_and_drop_reporting():
     assert buf.dropped == 0 and buf.drain() == []
 
 
+def test_span_parent_links():
+    """Every span record names the innermost span open on its thread when
+    it started; complete() records take the one open when they land."""
+    buf = spans.SpanBuffer()
+    with spans.task_context(buf, job="j", worker=0, task=1, attempt="a",
+                            kind="map"):
+        with spans.span("map:compute", cat="map"):
+            with spans.span("map:emit", cat="map") as args:
+                args["rows"] = 3
+            spans.complete("map:read", 1.0, 0.5, cat="map")
+        with spans.span("map:shuffle", cat="map"):
+            pass
+    by_name = {r["name"]: r for r in buf.drain()}
+    assert by_name["map:emit"]["parent"] == "map:compute"
+    assert by_name["map:emit"]["args"] == {"rows": 3}
+    assert by_name["map:read"]["parent"] == "map:compute"
+    assert by_name["map:compute"]["parent"] is None
+    assert by_name["map:shuffle"]["parent"] is None
+
+
+def test_explain_self_seconds_from_parent_links():
+    """Explain's self seconds: a row's seconds minus those of the spans
+    whose parent it is (direct children only)."""
+    from distributed_grep_tpu.runtime import explain as explain_mod
+
+    def span(name, dur, parent=None, **args):
+        return {"t": "span", "name": name, "dur": dur, "parent": parent,
+                "args": args}
+
+    events = [
+        span("map:compute", 1.0),
+        span("map:compute", 1.0),
+        span("engine:prep", 0.1, "map:compute"),
+        span("scan:nfa", 1.5, "map:compute", bytes=10, compiles=2),
+        span("engine:prep", 0.2, "scan:nfa"),
+        span("engine:wait", 0.5, "scan:nfa"),
+        span("engine:confirm", 0.6, "scan:nfa"),
+        span("engine:stitch", 0.1, "engine:confirm"),  # grandchild
+        span("map:emit", 0.3, "map:compute"),
+        span("map:wait", 0.25),
+    ]
+    agg = explain_mod.summarize_events(events)
+    st, nfa = agg["stages"], agg["modes"]["nfa"]
+    assert st["map:compute"]["seconds"] == 2.0
+    assert st["map:compute"]["self_seconds"] == pytest.approx(0.1)
+    assert nfa["self_seconds"] == pytest.approx(0.2)
+    assert nfa["compiles"] == 2
+    assert st["engine:confirm"]["self_seconds"] == pytest.approx(0.5)
+    assert st["engine:prep"] == {"count": 2, "seconds": 0.3,
+                                 "self_seconds": 0.3}
+    assert st["map:wait"]["self_seconds"] == 0.25
+
+
 def test_span_context_tags_and_nesting():
     buf = spans.SpanBuffer()
     assert not spans.active()
@@ -104,7 +157,8 @@ def test_span_context_tags_and_nesting():
 def test_span_emitters_noop_outside_context():
     """Disabled pipeline: emitters return immediately and buffer nothing."""
     spans.instant("nobody-home")
-    spans.scan_record("native", 10, 0.1)
+    with spans.scan_span("native") as rec:
+        assert rec is None  # nothing to fill, nothing recorded
     with spans.span("nothing"):
         pass
     cm = spans.span("x")
@@ -157,6 +211,117 @@ def test_engine_scan_no_record_without_context():
     eng = GrepEngine("needle", backend="cpu")
     res = eng.scan(b"needle\n")  # must not raise, must not need a buffer
     assert res.n_matches == 1
+
+
+ENGINE_SPANS = ("engine:prep", "engine:wait", "engine:decode",
+                "engine:confirm", "engine:stitch")
+
+
+def test_engine_spans_nest_under_the_scan():
+    """An interpret-mode Pallas scan emits the five engine:* spans as
+    children of its scan:<mode> span, which nests under the worker's
+    map:compute; its compile counter reads the compiles of the first
+    scan and none on the repeat."""
+    from distributed_grep_tpu.ops.engine import GrepEngine
+
+    eng = GrepEngine("needle", backend="device", interpret=True)
+    data = b"hay\nneedle here\nhay\n" * 200
+    buf = spans.SpanBuffer()
+    with spans.task_context(buf, job="j", worker=0, task=3, attempt="a",
+                            kind="map"):
+        with spans.span("map:compute", cat="map"):
+            eng.scan(data)
+        eng.scan(data)
+    recs = buf.drain()
+    scans = [r for r in recs if r["name"] == f"scan:{eng.mode}"]
+    assert [s["parent"] for s in scans] == ["map:compute", None]
+    for name in ENGINE_SPANS:
+        got = [r for r in recs if r["name"] == name]
+        assert len(got) >= 2, name  # each scan has its own
+        assert {r["parent"] for r in got} == {f"scan:{eng.mode}"}, name
+        assert all(r["task"] == 3 and r["cat"] == "engine" for r in got)
+    first, repeat = (s["args"]["compiles"] for s in scans)
+    assert first > 0 and repeat == 0
+
+
+def test_scan_batch_prep_is_a_child_of_map_compute(tmp_path):
+    """scan_batch's lookups and reads run before scan(): their
+    engine:prep spans have the worker's map:compute as parent, while the
+    scan's own prep nests under scan:<mode>."""
+    from distributed_grep_tpu.ops.engine import GrepEngine
+
+    p = tmp_path / "big.txt"
+    p.write_bytes(b"hay\nneedle here\nhay\n" * 100)
+    eng = GrepEngine("needle", backend="device", device_min_bytes=16)
+    buf = spans.SpanBuffer()
+    with spans.task_context(buf, job="j", worker=0, task=1, attempt="a",
+                            kind="map"):
+        with spans.span("map:compute", cat="map"):
+            out = eng.scan_batch([("big.txt", str(p))])
+    assert out[0][1].n_matches == 100
+    parents = [r["parent"] for r in buf.drain()
+               if r["name"] == "engine:prep"]
+    assert "map:compute" in parents and f"scan:{eng.mode}" in parents
+
+
+def test_collect_thread_spans_carry_the_task_tags():
+    """A multi-segment scan collects on pool threads: their spans (no
+    span is open on those threads, so no parent) keep the task's tags
+    instead of being dropped."""
+    from distributed_grep_tpu.ops.engine import GrepEngine
+
+    eng = GrepEngine("needle", backend="device", segment_bytes=4096,
+                     target_lanes=16)
+    data = b"hay\nneedle here\nhay\n" * 500
+    buf = spans.SpanBuffer()
+    with spans.task_context(buf, job="j", worker=2, task=5, attempt="a",
+                            kind="map"):
+        res = eng.scan(data)
+    assert res.n_matches == 500
+    recs = buf.drain()
+    pooled = [r for r in recs if r["name"] == "engine:decode"]
+    n_segs = -(-len(data) // 4096)
+    assert len(pooled) >= n_segs
+    for r in pooled:
+        assert r["parent"] is None
+        assert (r["job"], r["worker"], r["task"]) == ("j", 2, 5)
+    waits = [r["parent"] for r in recs if r["name"] == "engine:wait"]
+    assert waits.count(None) >= n_segs  # the fetches, on the pool
+    assert waits.count(f"scan:{eng.mode}") == n_segs  # the dispatches
+
+
+@pytest.mark.service
+def test_served_job_explain_has_wait_and_build_rows(tmp_path):
+    """A served job's explain reports the scheduler's map:wait and
+    reduce:wait (runnable to assigned, one per task) and the worker's
+    shuffle:build inside map:shuffle."""
+    from distributed_grep_tpu.runtime.service import GrepService
+
+    files = []
+    for i in range(3):
+        p = tmp_path / f"f{i}.txt"
+        p.write_bytes(b"needle %d\nhay\n" % i * 50)
+        files.append(str(p))
+    svc = GrepService(work_root=tmp_path / "root", spans=True)
+    try:
+        svc.start_local_workers(2)
+        jid = svc.submit(JobConfig(
+            input_files=files,
+            application="distributed_grep_tpu.apps.grep_tpu",
+            app_options={"pattern": "needle", "backend": "cpu"},
+            n_reduce=2, spans=True, batch_bytes=0,
+        ))
+        assert svc.wait_job(jid, timeout=60)
+        st = svc.job_explain(jid)["stages"]
+    finally:
+        svc.stop()
+    assert st["map:wait"]["count"] == 3
+    assert st["reduce:wait"]["count"] == 2
+    assert st["map:wait"]["seconds"] >= 0 and st["reduce:wait"]["seconds"] >= 0
+    assert st["shuffle:build"]["count"] == 3
+    assert (st["map:shuffle"]["self_seconds"]
+            <= st["map:shuffle"]["seconds"] - st["shuffle:build"]["seconds"]
+            + 1e-5)
 
 
 def test_span_batch_retry_dedup(tmp_path):
