@@ -47,7 +47,9 @@ EVENTS: dict[str, Event] = {
         "Per-scan engine span promoted from engine.stats, open over the "
         "scan (the engine:* spans are its children); the member is the "
         "kernel family that ran (scan:batch = one packed cross-file flush); "
-        "args.compiles = backend compiles the scan waited on.",
+        "args.compiles = backend compiles the scan waited on; device scans "
+        "add args.stitch_lines (boundary lines re-matched on the host) and "
+        "args.host_line_passes (host matcher passes, stitch and confirm).",
         members=("re", "native", "dfa", "nfa", "shift_and", "fdr",
                  "pairset", "approx", "batch"),
     ),
@@ -68,13 +70,14 @@ EVENTS: dict[str, Event] = {
     ),
     "engine:confirm": Event(
         ("span",), "engine", ("ops/device_scan.py",), (),
-        "Host confirm of device candidates (per-line, native dense pass, "
-        "FDR ConfirmSet).",
+        "Host confirm of device candidates (one native pass per bank over "
+        "the candidate lines, native dense pass, FDR ConfirmSet).",
     ),
     "engine:stitch": Event(
         ("span",), "engine", ("ops/engine.py", "ops/device_scan.py"), (),
-        "Stripe/segment boundary re-scan, the nullable-$ EOL fix-up and "
-        "the final sort into the ScanResult.",
+        "Stripe/segment boundary re-match (one native pass per bank over "
+        "the boundary lines), the nullable-$ EOL fix-up and the merge "
+        "into the ScanResult.",
     ),
     # ---------------------------------------------------------- worker spans
     "map:task": Event(

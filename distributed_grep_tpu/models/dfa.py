@@ -868,8 +868,8 @@ class DfaTable:
         """[n_states, 256] uint16 — for the native/C++ scanner oracle.
 
         Cached: a 10k-pattern Aho-Corasick bank densifies to ~30 MB, and
-        the engine's per-line confirm/stitch path calls this once per
-        suspect line."""
+        the engine's confirm/stitch path calls this once per batch of
+        suspect lines."""
         full = getattr(self, "_full_cache", None)
         if full is None:
             full = np.ascontiguousarray(self.trans[:, self.byte_to_cls])

@@ -280,7 +280,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     import time as _time
 
     t_wall0 = _time.perf_counter()
-    eng.stats = {"candidates": 0, "confirm_seconds": 0.0, "end_offsets": 0}
+    eng.stats = {"candidates": 0, "confirm_seconds": 0.0, "end_offsets": 0,
+                 "stitch_lines": 0, "host_line_passes": 0}
     # the ONE dict for this scan: collect()/prepare() run in pool
     # threads, where `eng.stats` would resolve to the POOL thread's
     # slot — references below go through this capture (except after a
@@ -490,16 +491,26 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
         with spans_mod.span("engine:wait", cat="engine"):
             return scan_jnp.sparse_nonzero(payload)
 
-    def confirm_lines(cand) -> None:
-        """Per-line host confirm for a sparse candidate-line set (the
-        shared tail of the span/cand filter paths)."""
-        good = []
-        for ln in cand:
-            start, end = lines_mod.line_span(nl, ln, len(data))
-            if eng._host_line_matcher(data[start:end]):
-                good.append(ln)
+    def host_lines_matching(data_, nl_, line_nos: np.ndarray) -> np.ndarray:
+        """eng._host_lines_matching, its passes counted into this scan's
+        stats (the confirm and stitch legs share it)."""
+        good = eng._host_lines_matching(data_, nl_, line_nos)
         with state_lock:
-            device_lines.update(good)
+            st["host_line_passes"] += eng._host_line_passes(line_nos.size)
+        return good
+
+    def confirm_lines(cand) -> None:
+        """Host confirm of a sparse candidate-line set, in one batch (the
+        shared tail of the span/cand filter paths)."""
+        lns = np.fromiter(cand, dtype=np.int64, count=len(cand))
+        lns.sort()
+        good = host_lines_matching(data, nl, lns)
+        with state_lock:
+            device_lines.update(good.tolist())
+
+    def stitch_matching(data_, nl_, line_nos: np.ndarray) -> np.ndarray:
+        st["stitch_lines"] += int(line_nos.size)
+        return host_lines_matching(data_, nl_, line_nos)
 
     def dense_native_confirm(seg_start: int, seg_len: int) -> int:
         """Candidate-dense segment: one native DFA pass (C, ~GB/s)
@@ -1077,10 +1088,9 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     # boundary lines (stripe/segment heads, where the filter's all-ones
     # seed under-reports) are restored by the stitching pass below.
     with spans_mod.span("engine:stitch", cat="engine"):
-        stitched = lines_mod.stitch_lines(
-            device_lines, data, nl, boundaries, eng._host_line_matcher
+        lines_arr = lines_mod.stitch_lines(
+            device_lines, data, nl, boundaries, stitch_matching
         )
-        lines_arr = np.asarray(sorted(stitched), dtype=np.int64)
     if use_mesh and psum_totals:
         # ICI-collective candidate tally across all segments — the
         # cross-check dryrun_multichip asserts against the host count.

@@ -36,6 +36,7 @@ import os as _os
 import re as _re
 import time as _time_mod
 from dataclasses import dataclass
+from functools import partial
 
 import numpy as np
 
@@ -2067,13 +2068,37 @@ class GrepEngine:
         self.stats = {"end_offsets": int(offsets.size)}
         return ScanResult(lns.astype(np.int64), int(lns.size), len(data))
 
-    def _host_line_matcher(self, line: bytes) -> bool:
+    def _host_lines_batched(self) -> bool:
+        """True when the host re-match is one native pass per DFA bank
+        over the whole batch of lines; False for the approx model and
+        the DFA-less re rescue, which match line by line."""
+        return self.approx is None and bool(self.tables)
+
+    def _host_lines_matching(
+        self, data: bytes, nl: np.ndarray, line_nos: np.ndarray
+    ) -> np.ndarray:
+        """The subset of ``line_nos`` (sorted, unique, 1-based lines of
+        ``data``) that match: the host verdict of the confirm and stitch
+        legs of the device scan."""
+        if self._host_lines_batched():
+            return lines_mod.lines_matching(self.tables, data, nl, line_nos)
         if self.approx is not None:
-            return approx_line_matches(self.approx, line)
-        if not self.tables and self._re_fallback is not None:
+            match = partial(approx_line_matches, self.approx)
+        elif self._re_fallback is not None:
             # DFA-less NFA rescue (expansion-cap patterns): re is the oracle
-            return self._re_fallback.search(line) is not None
-        return any(reference_scan(t, line).size > 0 for t in self.tables)
+            match = self._re_fallback.search
+        else:
+            return np.zeros(0, dtype=np.int64)
+        keep = [
+            ln for ln in np.asarray(line_nos, dtype=np.int64).tolist()
+            if match(data[slice(*lines_mod.line_span(nl, ln, len(data)))])
+        ]
+        return np.asarray(keep, dtype=np.int64)
+
+    def _host_line_passes(self, n_lines: int) -> int:
+        """Host matcher passes one `_host_lines_matching` call over
+        ``n_lines`` lines makes: one per bank, or one per line."""
+        return len(self.tables) if self._host_lines_batched() else n_lines
 
     def _device_tables(self, dev=None) -> list[tuple]:
         """Per-bank device-resident scan tables, uploaded once per engine

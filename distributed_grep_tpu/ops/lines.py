@@ -7,19 +7,25 @@ first newline; the stripe's head partial line may have false negatives
 (matches spanning the boundary) and — for '^'/'$' patterns — false
 positives (the device treats the stripe start as a line start and the
 stripe tail as a line end).  The fix is exact and local: every line that
-contains a stripe boundary is re-scanned on the host with the native DFA
-scanner, and the host verdict *replaces* the device verdict for that line.
-This is the long-context analogue of carrying block state in ring
-attention (SURVEY.md §5): instead of carrying, we re-derive the tiny
-boundary-dependent region from its true line start.
+contains a stripe boundary is re-matched on the host, and the host verdict
+*replaces* the device verdict for that line.  The re-match is one native
+DFA pass per bank over all the suspect lines (`lines_matching`, behind
+the engine's `_host_lines_matching`), each line walked alone from the
+start state — one call, not one per line.  Without libdgrep the pass
+scans the suspect lines joined by '\\n', which is as exact: the tables
+reset to the start state on every '\\n', '^' sees each line's start and
+'$' the '\\n' after it.  This is the long-context analogue of carrying
+block state in ring attention (SURVEY.md §5): instead of carrying, we
+re-derive the tiny boundary-dependent region from its true line start.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Iterable
+from typing import Callable, Collection, Iterable
 
 import numpy as np
 
+from distributed_grep_tpu.models.dfa import DfaTable, reference_scan
 from distributed_grep_tpu.ops.layout import Layout
 from distributed_grep_tpu.utils import native
 
@@ -66,33 +72,84 @@ def line_span(nl_index: np.ndarray, line_no: int, n_bytes: int) -> tuple[int, in
 
 def boundary_lines(
     boundaries: Iterable[int], nl_index: np.ndarray, n_bytes: int
-) -> set[int]:
-    """1-based line numbers containing any of the given byte positions."""
-    out = set()
-    for p in boundaries:
-        if 0 < p < n_bytes:
-            out.add(int(np.searchsorted(nl_index, p, side="right")) + 1)
-    return out
+) -> np.ndarray:
+    """Sorted unique 1-based numbers of the lines containing any of the
+    given byte positions (0 < p < n_bytes).  A position on the data's
+    final '\\n' names no further line."""
+    p = np.fromiter(boundaries, dtype=np.int64)
+    p = p[(p > 0) & (p < n_bytes)]
+    lns = np.unique(np.searchsorted(nl_index, p, side="right") + 1)
+    ends_nl = nl_index.size > 0 and int(nl_index[-1]) == n_bytes - 1
+    n_lines = nl_index.size + (0 if ends_nl else 1)
+    return lns[lns <= n_lines].astype(np.int64)
+
+
+def join_lines(data: bytes, nl_index: np.ndarray, line_nos: np.ndarray) -> bytes:
+    """The given lines of ``data`` (sorted 1-based numbers), each followed
+    by one '\\n' — line i of the result is line line_nos[i-1] verbatim."""
+    from distributed_grep_tpu.runtime.columnar import gather_ranges, line_spans
+
+    n = len(data)
+    starts, ends = line_spans(line_nos, nl_index, n)
+    # each range takes its line's own '\n'; only the last line of the
+    # data can lack one, and sorted input puts it last
+    joined, _ = gather_ranges(
+        np.frombuffer(data, dtype=np.uint8), starts, np.minimum(ends + 1, n)
+    )
+    if int(ends[-1]) == n:
+        joined += b"\n"
+    return joined
+
+
+def lines_matching(
+    tables: list[DfaTable], data: bytes, nl_index: np.ndarray,
+    line_nos: np.ndarray,
+) -> np.ndarray:
+    """The subset of ``line_nos`` (sorted, unique, 1-based) whose lines
+    match any of the DFA ``tables``: one native call per table walks every
+    line alone from the start state.  Without libdgrep, one scan per
+    table over the lines joined by '\\n' (exact under the tables' newline
+    reset), mapped back by position in the joined buffer."""
+    line_nos = np.asarray(line_nos, dtype=np.int64)
+    if line_nos.size == 0 or not tables:
+        return np.zeros(0, dtype=np.int64)
+    hit = np.zeros(line_nos.size, dtype=bool)
+    joined = joined_nl = None
+    for t in tables:
+        h = native.dfa_lines(data, nl_index, line_nos, t.full_table(),
+                             t.accept, t.accept_eol, t.start)
+        if h is not None:
+            hit |= h
+            continue
+        if joined is None:
+            joined = join_lines(data, nl_index, line_nos)
+            joined_nl = newline_index(joined)  # one '\n' per joined line
+        offsets = reference_scan(t, joined)
+        if offsets.size:
+            idx = line_of_offsets(offsets, joined_nl) - 1
+            hit[idx[idx < line_nos.size]] = True
+    return line_nos[hit]
 
 
 def stitch_lines(
-    device_lines: set[int],
+    device_lines: Collection[int],
     data: bytes,
     nl_index: np.ndarray,
     boundaries: Iterable[int],
-    host_line_matcher: Callable[[bytes], bool],
-) -> set[int]:
-    """Replace the device verdict with the host verdict on every line that
-    contains a stripe/segment boundary."""
+    host_lines_matching: Callable[[bytes, np.ndarray, np.ndarray], np.ndarray],
+) -> np.ndarray:
+    """Sorted matched lines: the device's, with the host verdict replacing
+    it on every line that contains a stripe/segment boundary.
+    ``host_lines_matching(data, nl_index, suspects)`` returns the matching
+    subset of the sorted suspect line numbers."""
+    dev = np.fromiter(device_lines, dtype=np.int64, count=len(device_lines))
+    dev.sort()
     suspects = boundary_lines(boundaries, nl_index, len(data))
-    if not suspects:
-        return device_lines
-    result = set(device_lines) - suspects
-    for line_no in suspects:
-        start, end = line_span(nl_index, line_no, len(data))
-        if host_line_matcher(data[start:end]):
-            result.add(line_no)
-    return result
+    if not suspects.size:
+        return dev
+    kept = dev[~np.isin(dev, suspects, assume_unique=True)]
+    hits = host_lines_matching(data, nl_index, suspects)
+    return np.insert(kept, np.searchsorted(kept, hits), hits)
 
 
 def newline_index(data: bytes) -> np.ndarray:

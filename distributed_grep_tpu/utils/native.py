@@ -131,6 +131,21 @@ def _bind(lib: ctypes.CDLL) -> None:
         ctypes.c_size_t,
         ctypes.POINTER(ctypes.c_uint32),
     ]
+    if hasattr(lib, "dgrep_dfa_lines"):
+        lib.dgrep_dfa_lines.restype = None
+        lib.dgrep_dfa_lines.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_uint16),
+            ctypes.c_char_p,
+            ctypes.c_char_p,
+            ctypes.c_uint32,
+            ctypes.POINTER(ctypes.c_uint8),
+        ]
     if hasattr(lib, "dgrep_dfa_scan_mt"):
         lib.dgrep_dfa_scan_mt.restype = ctypes.c_size_t
         lib.dgrep_dfa_scan_mt.argtypes = [
@@ -354,6 +369,41 @@ def dfa_scan(
         if n <= cap:
             return np.ctypeslib.as_array(buf)[:n].copy(), int(final.value)
         cap = n
+
+
+def dfa_lines(
+    data: bytes,
+    nl: np.ndarray,  # [n] int64 '\n' offsets of data, ascending
+    linenos: np.ndarray,  # 1-based lines to match
+    table: np.ndarray,  # [n_states, 256] uint16 next-state table
+    accept: np.ndarray,  # [n_states] bool/uint8
+    accept_eol: np.ndarray,  # [n_states] bool/uint8: match iff at line end
+    start_state: int = 0,
+) -> np.ndarray | None:
+    """Per-line match verdicts (bool per entry of ``linenos``): each line
+    walked alone from ``start_state`` in one native call, or None when
+    libdgrep is unavailable (caller: ops/lines.lines_matching's joined
+    scan)."""
+    lib = _try_load()
+    if lib is None or not hasattr(lib, "dgrep_dfa_lines"):
+        return None
+    nl = np.ascontiguousarray(nl, dtype=np.int64)
+    linenos = np.ascontiguousarray(linenos, dtype=np.int64)
+    table = np.ascontiguousarray(table, dtype=np.uint16)
+    hit = np.empty(linenos.size, dtype=np.uint8)
+    if not isinstance(data, bytes):  # a bytearray or memoryview
+        data = np.frombuffer(data, dtype=np.uint8).ctypes.data_as(ctypes.c_char_p)
+    lib.dgrep_dfa_lines(
+        data, len(data),
+        nl.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), nl.size,
+        linenos.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), linenos.size,
+        table.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
+        np.ascontiguousarray(accept, dtype=np.uint8).tobytes(),
+        np.ascontiguousarray(accept_eol, dtype=np.uint8).tobytes(),
+        start_state,
+        hit.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+    )
+    return hit.view(bool)
 
 
 # --- Literal-set candidate confirm (FDR filter path, models/fdr.py) --------

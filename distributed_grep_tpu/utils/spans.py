@@ -243,7 +243,8 @@ def instant(name: str, cat: str = "task", **args) -> None:
 
 # Engine stats keys promoted into scan telemetry records when present
 # (ops/engine.py / ops/device_scan.py populate them per scan).
-_SCAN_STAT_KEYS = ("candidates", "end_offsets")
+_SCAN_STAT_KEYS = ("candidates", "end_offsets", "stitch_lines",
+                   "host_line_passes")
 
 
 def count_compile() -> None:

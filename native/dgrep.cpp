@@ -169,6 +169,38 @@ size_t dgrep_dfa_scan(const uint8_t* data, size_t len,
     return count;
 }
 
+// Per-line DFA verdicts for a batch of lines (1-based `linenos`, any
+// order) of `data`, whose '\n' offsets are `nl` (ascending).  Each line is
+// walked alone from `start_state`, exactly as grep sees it: hit[i] = 1 iff
+// a match ends inside line i (an accepting state after one of its bytes,
+// or an accepting start state: the empty match) or at its end
+// (`accept_eol` of the state after its last byte; may be null).  One call
+// re-matches every suspect line of a scan against one table.
+void dgrep_dfa_lines(const uint8_t* data, int64_t n_bytes,
+                     const int64_t* nl, int64_t n_nl,
+                     const int64_t* linenos, int64_t n,
+                     const uint16_t* table, const uint8_t* accept,
+                     const uint8_t* accept_eol, uint32_t start_state,
+                     uint8_t* hit) {
+    for (int64_t i = 0; i < n; ++i) {
+        int64_t ln = linenos[i];
+        if (ln < 1 || ln - 2 >= n_nl) {  // no such line
+            hit[i] = 0;
+            continue;
+        }
+        int64_t lo = (ln == 1) ? 0 : nl[ln - 2] + 1;
+        int64_t hi = (ln - 1 < n_nl) ? nl[ln - 1] : n_bytes;
+        uint32_t s = start_state;
+        uint8_t m = accept[s];
+        for (int64_t p = lo; p < hi && !m; ++p) {
+            s = table[((size_t)s << 8) | data[p]];
+            m = accept[s];
+        }
+        if (!m && accept_eol) m = accept_eol[s];
+        hit[i] = m;
+    }
+}
+
 // Multithreaded DFA scan.  Chunk boundaries snap to the byte AFTER a
 // newline; because every state's '\n' transition is the start state (the
 // newline-reset invariant all tables here share, models/dfa.py DfaTable),

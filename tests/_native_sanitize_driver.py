@@ -139,6 +139,16 @@ def surface() -> None:
     mt = native.dfa_scan_mt(data, table, accept, n_threads=4)
     check(mt.tolist() == offs.tolist(), "dfa_scan_mt == sequential")
 
+    # --- dfa_lines: per-line verdicts, first/last/absent lines -------------
+    nl64 = nl.astype(np.int64)
+    n_lines = nl.size + (0 if data.endswith(b"\n") else 1)
+    lns = np.array([1, 2, n_lines // 2, n_lines, n_lines + 5, 0], np.int64)
+    eol = np.zeros_like(accept)
+    hits = native.dfa_lines(data, nl64, lns, table, accept, eol)
+    lines = data.split(b"\n")
+    want = [0 < ln <= n_lines and b"nedle" in lines[ln - 1] for ln in lns]
+    check(hits.tolist() == want, "dfa_lines")
+
     # --- ConfirmSet: folds, shorts, regrow-sized candidate sets ------------
     pats = [b"nedle", b"ab", b"z", b"needle", b"\xff\xferaw"]
     for ci in (False, True):
