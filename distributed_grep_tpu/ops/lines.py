@@ -21,7 +21,7 @@ re-derive the tiny boundary-dependent region from its true line start.
 
 from __future__ import annotations
 
-from typing import Callable, Collection, Iterable
+from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
@@ -61,6 +61,25 @@ def unique_match_lines(offsets: np.ndarray, nl_index: np.ndarray) -> np.ndarray:
     if out is not None:
         return out
     return np.unique(line_of_offsets(offsets, nl_index)).astype(np.int64)
+
+
+def range_union(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """Sorted unique int64 numbers in the union of the closed ranges
+    ``[lo[i], hi[i]]``, ``lo`` nondecreasing (a range with lo > hi is
+    empty).  Each range contributes only the numbers past the furthest
+    end before it, so the expansion comes out sorted and unique with no
+    sort: one ``np.repeat`` + ``arange``."""
+    lo = np.asarray(lo, dtype=np.int64)
+    hi = np.asarray(hi, dtype=np.int64)
+    if lo.size == 0:
+        return np.zeros(0, dtype=np.int64)
+    first = lo.copy()
+    first[1:] = np.maximum(lo[1:], np.maximum.accumulate(hi)[:-1] + 1)
+    n = np.maximum(hi - first + 1, 0)
+    total = int(n.sum())
+    # each output position minus the index its range starts at, plus the
+    # range's first number
+    return np.arange(total, dtype=np.int64) + np.repeat(first - (np.cumsum(n) - n), n)
 
 
 def line_span(nl_index: np.ndarray, line_no: int, n_bytes: int) -> tuple[int, int]:
@@ -132,7 +151,7 @@ def lines_matching(
 
 
 def stitch_lines(
-    device_lines: Collection[int],
+    device_lines: np.ndarray | Sequence[np.ndarray],
     data: bytes,
     nl_index: np.ndarray,
     boundaries: Iterable[int],
@@ -140,10 +159,22 @@ def stitch_lines(
 ) -> np.ndarray:
     """Sorted matched lines: the device's, with the host verdict replacing
     it on every line that contains a stripe/segment boundary.
+    ``device_lines`` is one sorted, unique int64 array of 1-based line
+    numbers or a list of them (one per segment and confirm batch, in any
+    order; a line two of them report counts once).
     ``host_lines_matching(data, nl_index, suspects)`` returns the matching
     subset of the sorted suspect line numbers."""
-    dev = np.fromiter(device_lines, dtype=np.int64, count=len(device_lines))
-    dev.sort()
+    parts = [device_lines] if isinstance(device_lines, np.ndarray) \
+        else list(device_lines)
+    if len(parts) == 1:
+        # one scan segment: nothing to merge.  Every numpy call here
+        # hands the GIL to the other worker slot and waits to get it
+        # back, so the merge is skipped where it would change nothing.
+        dev = np.asarray(parts[0], dtype=np.int64)
+    elif parts:
+        dev = np.unique(np.concatenate(parts)).astype(np.int64, copy=False)
+    else:
+        dev = np.zeros(0, dtype=np.int64)
     suspects = boundary_lines(boundaries, nl_index, len(data))
     if not suspects.size:
         return dev

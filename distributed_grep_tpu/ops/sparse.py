@@ -15,6 +15,14 @@ from distributed_grep_tpu.ops.layout import Layout
 from distributed_grep_tpu.ops.pallas_scan import LANE_COLS, LANES_PER_BLOCK, SUBLANES
 
 
+# The bit index of a uint32 power of two from one multiply and one shift
+# (a de Bruijn sequence): x * _DE_BRUIJN >> 27 is distinct for each bit.
+_DE_BRUIJN = np.uint32(0x077CB531)
+_LOW_BIT = np.zeros(32, dtype=np.int64)
+_LOW_BIT[(np.uint32(1) << np.arange(32, dtype=np.uint32)) * _DE_BRUIJN
+         >> np.uint32(27)] = np.arange(32)
+
+
 def offsets_from_sparse_lane_bytes(
     idx: np.ndarray, vals: np.ndarray, layout: Layout
 ) -> np.ndarray:
@@ -37,6 +45,14 @@ def offsets_from_sparse_lane_bytes(
     return offsets
 
 
+def _word_lanes(idx: np.ndarray, layout: Layout) -> tuple[np.ndarray, np.ndarray]:
+    """(word row w, lane) of the flat indices of a Pallas word plane
+    (chunk//32, S, 128): index (w*S + s)*128 + l is lane
+    (s//32)*4096 + (s%32)*128 + l, which is s*128 + l, so one divmod by
+    the lane count gives both."""
+    return np.divmod(idx, layout.lanes)
+
+
 def span_starts_from_sparse_words(
     idx: np.ndarray, layout: Layout
 ) -> np.ndarray:
@@ -47,12 +63,7 @@ def span_starts_from_sparse_words(
     lines overlapping it."""
     if idx.size == 0:
         return np.zeros(0, dtype=np.int64)
-    S = layout.lanes // LANE_COLS
-    l = idx % LANE_COLS
-    rest = idx // LANE_COLS
-    s = rest % S
-    w = rest // S
-    lane = (s // SUBLANES) * LANES_PER_BLOCK + (s % SUBLANES) * LANE_COLS + l
+    w, lane = _word_lanes(idx, layout)
     starts = lane * layout.chunk + w * 32
     starts = starts[starts < layout.n_real]
     starts.sort()
@@ -94,18 +105,20 @@ def offsets_from_sparse_words(
     = (s//32)*4096 + (s%32)*128 + l.  Returns sorted end offsets."""
     if idx.size == 0:
         return np.zeros(0, dtype=np.int64)
-    S = layout.lanes // LANE_COLS
-    l = idx % LANE_COLS
-    rest = idx // LANE_COLS
-    s = rest % S
-    w = rest // S
-    lane = (s // SUBLANES) * LANES_PER_BLOCK + (s % SUBLANES) * LANE_COLS + l
+    w, lane = _word_lanes(idx, layout)
+    base = lane * layout.chunk + w * 32 + 1  # the end offset of bit 0
+    v = np.asarray(vals).astype(np.uint32, copy=False)
     out = []
-    for t in range(32):
-        sel = (vals >> np.uint32(t)) & np.uint32(1) != 0
-        if sel.any():
-            c = w[sel] * 32 + t
-            out.append(lane[sel] * layout.chunk + c + 1)
+    # one pass per set bit of the fullest word, each over the words that
+    # still hold a bit: peel every word's lowest set bit
+    while True:
+        keep = v != 0
+        v, base = v[keep], base[keep]
+        if not v.size:
+            break
+        low = v & (~v + np.uint32(1))
+        out.append(base + _LOW_BIT[(low * _DE_BRUIJN) >> np.uint32(27)])
+        v = v ^ low
     offsets = np.concatenate(out) if out else np.zeros(0, dtype=np.int64)
     offsets = offsets[offsets <= layout.n_real]
     offsets.sort()

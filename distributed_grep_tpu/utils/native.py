@@ -631,7 +631,10 @@ def unique_lines_native(nl: np.ndarray, ends: np.ndarray) -> np.ndarray | None:
         return None
     if not env_native_records():
         return None
-    nl = np.ascontiguousarray(nl, dtype=np.uint64)
+    nl = np.ascontiguousarray(nl)
+    # newline offsets are nonnegative: an int64 index is read as uint64
+    # in place instead of copied
+    nl = nl.view(np.uint64) if nl.dtype == np.int64 else nl.astype(np.uint64)
     ends = np.ascontiguousarray(ends, dtype=np.int64)
     out = np.empty(ends.size, dtype=np.int64)
     n = lib.dgrep_unique_lines(

@@ -43,7 +43,7 @@ def test_sharded_scan_matches_host_oracle(mesh8):
     packed_np = np.asarray(packed)
     offsets = lines_mod.match_offsets_from_packed(packed_np, lay)
     nl = lines_mod.newline_index(data)
-    device_lines = set(np.unique(lines_mod.line_of_offsets(offsets, nl)).tolist())
+    device_lines = np.unique(lines_mod.line_of_offsets(offsets, nl))
     stitched = lines_mod.stitch_lines(
         device_lines,
         data,
@@ -96,7 +96,7 @@ def test_two_axis_mesh_scan():
     packed_np = np.asarray(packed)
     offsets = lines_mod.match_offsets_from_packed(packed_np, lay)
     nl = lines_mod.newline_index(data)
-    device_lines = set(np.unique(lines_mod.line_of_offsets(offsets, nl)).tolist())
+    device_lines = np.unique(lines_mod.line_of_offsets(offsets, nl))
     stitched = lines_mod.stitch_lines(
         device_lines, data, nl, lay.stripe_starts().tolist(),
         partial(lines_mod.lines_matching, [table]),
@@ -129,7 +129,7 @@ def test_product_axis_sharding_uses_all_devices():
     np.testing.assert_array_equal(np.asarray(neigh), np.roll(last_exit_per_dev, 1))
     offsets = lines_mod.match_offsets_from_packed(np.asarray(packed), lay)
     nl = lines_mod.newline_index(data)
-    device_lines = set(np.unique(lines_mod.line_of_offsets(offsets, nl)).tolist())
+    device_lines = np.unique(lines_mod.line_of_offsets(offsets, nl))
     stitched = lines_mod.stitch_lines(
         device_lines, data, nl, lay.stripe_starts().tolist(),
         partial(lines_mod.lines_matching, [table]),
@@ -167,7 +167,7 @@ def test_pattern_set_step_matches_oracle():
     offsets = lines_mod.match_offsets_from_packed(np.asarray(packed), lay)
     assert int(total) >= offsets.size  # total counts padded tail positions too
     nl = lines_mod.newline_index(data)
-    device_lines = set(np.unique(lines_mod.line_of_offsets(offsets, nl)).tolist())
+    device_lines = np.unique(lines_mod.line_of_offsets(offsets, nl))
 
     any_bank = partial(lines_mod.lines_matching, tables)
     stitched = lines_mod.stitch_lines(
@@ -195,7 +195,7 @@ def test_pattern_set_step_bank_padding():
     packed, total = sharded_pattern_set_step(arr, tables, mesh)
     offsets = lines_mod.match_offsets_from_packed(np.asarray(packed), lay)
     nl = lines_mod.newline_index(data)
-    got = set(np.unique(lines_mod.line_of_offsets(offsets, nl)).tolist())
+    got = np.unique(lines_mod.line_of_offsets(offsets, nl))
 
     any_bank = partial(lines_mod.lines_matching, tables)
     got = lines_mod.stitch_lines(got, data, nl, lay.stripe_starts().tolist(), any_bank)

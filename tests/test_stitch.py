@@ -124,7 +124,7 @@ def test_boundary_lines_and_stitch_replace_the_device_verdict():
     # device: a false positive on suspect 3, a miss on suspect 4, and a
     # false positive on line 1, which is no suspect and so stays
     out = lines_mod.stitch_lines(
-        {1, 3}, data, nl, [4, 11, 13],
+        np.array([1, 3]), data, nl, [4, 11, 13],
         lambda d, n, lns: lines_mod.lines_matching(table, d, n, lns),
     )
     assert out.dtype == np.int64 and out.tolist() == [1, 2, 4]
