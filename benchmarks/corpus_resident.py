@@ -48,7 +48,6 @@ if (_root / "distributed_grep_tpu").is_dir():
 # ``--device`` drops the pin for a run on the chip.
 if "--device" not in sys.argv:
     os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("DGREP_NO_CALIBRATE", "1")
 import jax  # noqa: E402
 
 if "--device" not in sys.argv:

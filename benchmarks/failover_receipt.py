@@ -73,7 +73,6 @@ def _serve(work_root: Path, port: int, workers: int, ttl_s: float,
         "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
         "PYTHONPATH": str(_root),
         "JAX_PLATFORMS": "cpu",
-        "DGREP_NO_CALIBRATE": "1",
         "DGREP_LOG": "WARNING",
         "DGREP_LEASE_TTL_S": str(ttl_s),
     }

@@ -17,15 +17,13 @@ measures both sides of ISSUE 3's acceptance bar:
 ``--timing slope`` packs the whole corpus into one buffer and slope-times
 the device-resident kernel (utils/slope.py via baseline_configs.slope_gbps)
 — the per-chip kernel number, where e2e wall time also measures the
-host link.  DGREP_NO_CALIBRATE=1 is forced for
-deterministic FDR plans.  Prints exactly one JSON line.
+host link.  Prints exactly one JSON line.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -37,8 +35,6 @@ if not (_root / "distributed_grep_tpu").is_dir():
     _root = _root.parent
 if (_root / "distributed_grep_tpu").is_dir():
     sys.path.insert(0, str(_root))
-
-os.environ.setdefault("DGREP_NO_CALIBRATE", "1")  # deterministic FDR plans
 
 import numpy as np
 

@@ -46,7 +46,6 @@ if (_root / "distributed_grep_tpu").is_dir():
 
 # CPU-pinned (CLAUDE.md environment rules): ASSIGN, never setdefault.
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("DGREP_NO_CALIBRATE", "1")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -54,7 +54,6 @@ if (_root / "distributed_grep_tpu").is_dir():
 # TPU default backend would price the submit path with device dispatch
 # latency, and this benchmark measures host-side model build.
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("DGREP_NO_CALIBRATE", "1")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -43,20 +43,10 @@ KNOBS: dict[str, Knob] = {
         "one parse (ops/layout.env_batch_bytes) shared by the planner and "
         "the engine packing cap.",
     ),
-    "DGREP_NO_CALIBRATE": Knob(
-        "ops/device_scan.py", "unset",
-        "1 disables the FDR tuner's init confirm probe + post-scan retune "
-        "(deterministic CI).",
-    ),
     "DGREP_CONFIRM_THREADS": Knob(
         "models/fdr.py", "min(8, cpu_count)",
         "Declared confirm-thread fan of the deployment; prices the FDR "
         "filter/confirm trade.",
-    ),
-    "DGREP_SWAR": Knob(
-        "ops/pallas_scan.py", "unset",
-        "1 routes eligible short equality-class patterns through the SWAR "
-        "packed shift-and kernel (default off: no real-chip receipt yet).",
     ),
     "DGREP_SPOOL_DIR": Knob(
         "runtime/http_transport.py", "system temp dir",

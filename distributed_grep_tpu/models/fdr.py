@@ -138,11 +138,9 @@ NATIVE_SCAN_GBPS_PER_THREAD = 0.33
 
 @dataclass(frozen=True)
 class Pricing:
-    """The tuner's cost constants as one value, so the runtime can replace
-    the compile-time assumptions with MEASURED numbers (self-calibration,
-    VERDICT r2 item 3): the engine probes ConfirmSet at init (catching e.g.
-    the ~100x-slower Python-fallback confirm on hosts without the native
-    lib) and retunes from real engine.stats after the first scan."""
+    """The tuner's cost constants as one value: the engine prices its plan
+    once, at construction, with its active chip count
+    (ops/engine._fdr_base_pricing)."""
 
     confirm_ps_per_candidate: float  # single-thread wall, ps
     confirm_threads: int
@@ -181,31 +179,6 @@ def default_pricing() -> Pricing:
         fp_bias=EMPIRICAL_FP_BIAS,
         overlap_residue=OVERLAP_RESIDUE,
     )
-
-
-def probe_confirm_ps(confirm_set, n: int = 1 << 15, seed: int = 0,
-                     n_threads: int = 1) -> float:
-    """Measured wall ps/candidate of THIS host's ConfirmSet at the given
-    thread fan on synthetic random candidates (~ms; run once per engine
-    init at n_threads=1; the post-scan retune probes again at the actual
-    fan to measure parallel efficiency instead of assuming ideal scaling).
-
-    Random offsets under-represent the bloom-pass bias of real FDR
-    candidates (~2x, see CONFIRM_PS_PER_CANDIDATE), so callers should gate
-    retuning on a wide ratio — the probe exists to catch order-of-magnitude
-    mispricing (missing native lib, exotic hosts), and the post-scan stats
-    retune handles the fine constants."""
-    import time
-
-    rng = np.random.default_rng(seed)
-    buf = rng.integers(0, 256, size=1 << 20, dtype=np.uint8).tobytes()
-    ends = np.sort(rng.integers(8, len(buf), size=n)).astype(np.uint64)
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        confirm_set.confirm(buf, ends, n_threads=n_threads)
-        best = min(best, time.perf_counter() - t0)
-    return best / n * 1e12
 
 
 def pair_hash(b0: np.ndarray | int, b1: np.ndarray | int, domain: int, which: int = 0):

@@ -86,42 +86,6 @@ def try_compile_shift_and(
     )
 
 
-# ------------------------------------------------------------- SWAR packing
-
-# The SWAR shift-and kernel (ops/pallas_scan.swar_shift_and_scan_words)
-# packs FOUR stripes' automata into each u32 lane element (one byte-plane
-# per stripe), so state, B-mask build, and the coarse accumulate all run
-# on 4 corpus bytes per i32 lane element instead of one.  That needs the
-# whole automaton — state bits AND match bit — to fit one byte, and every
-# checked symbol class to be a small set of exact byte VALUES (the SWAR
-# zero-byte detect tests equality; range compares have no cheap packed
-# form).  Wildcard positions (the rare-class filter) cost nothing, as in
-# the unpacked kernel.
-SWAR_MAX_SYMBOLS = 8  # state + match bit within each stripe's byte
-SWAR_MAX_VALUES = 16  # total equality tests per byte step (ALU budget)
-
-
-def swar_values(model: ShiftAndModel) -> list[tuple[int, ...]] | None:
-    """Per-symbol byte values for the SWAR packed kernel, or None when the
-    model is ineligible (too long, non-singleton ranges, value budget).
-    An empty tuple marks a wildcard position (checked nowhere)."""
-    if model.length > SWAR_MAX_SYMBOLS:
-        return None
-    out: list[tuple[int, ...]] = []
-    total = 0
-    for ranges in model.sym_ranges:
-        vals = []
-        for lo, hi in ranges:
-            if lo != hi:
-                return None  # a real range: no packed equality form
-            vals.append(lo)
-        total += len(vals)
-        out.append(tuple(vals))
-    if total > SWAR_MAX_VALUES:
-        return None
-    return out
-
-
 # ------------------------------------------------------- rare-class filter
 
 # Byte-frequency prior for choosing which classes the device filter checks.

@@ -3,24 +3,21 @@ round 5 — VERDICT r4 item 6: the 2,100-line engine monolith).
 
 This module owns the per-segment device dispatch pipeline — segment
 prepare/feed double-buffering, the kernel-route selection, the collect
-pool with its confirm/dense-guard/defeat closures, and the FDR
-self-calibration trio — operating on a ``GrepEngine`` instance
-(``eng``).  The engine keeps thin delegate methods so the public surface
-(and the tests poking ``eng._scan_device`` / ``eng._maybe_retune_fdr``)
-is unchanged.  No behavior change: the code is the engine's, moved.
+pool with its confirm/dense-guard/defeat closures — operating on a
+``GrepEngine`` instance (``eng``).  The engine keeps a thin delegate
+method so the public surface (and the tests poking ``eng._scan_device``)
+is unchanged.
 """
 
 from __future__ import annotations
 
 import itertools as _itertools
-import os as _os
 import threading as _threading_mod
 import time as _time_mod
 from contextlib import contextmanager
 
 import numpy as np
 
-from distributed_grep_tpu.models.fdr import FdrError, compile_fdr
 from distributed_grep_tpu.ops import lines as lines_mod
 from distributed_grep_tpu.utils import lockdep as _lockdep
 from distributed_grep_tpu.utils import spans as spans_mod
@@ -107,150 +104,6 @@ class _DaemonPool:
                 t.join()
 
 
-
-
-def calibrate_fdr_confirm(eng) -> None:
-    """Init-time probe: measure this host's single-thread ConfirmSet
-    cost on synthetic candidates; if it is >4x off the priced constant
-    (either way), recompile the filter plan under measured pricing.
-    Random-offset probes under-read the FDR-candidate bias ~2x, hence
-    the wide gate — the post-scan retune handles fine constants."""
-    from dataclasses import replace as _replace
-
-    from distributed_grep_tpu.models.fdr import probe_confirm_ps
-
-    eng._fdr_pricing = eng._fdr_base_pricing()
-    eng._fdr_retuned = False
-    if _os.environ.get("DGREP_NO_CALIBRATE"):
-        return
-    measured = probe_confirm_ps(eng._fdr_confirm)
-    eng.calibration = {"confirm_probe_ps": measured}
-    ratio = measured / eng._fdr_pricing.confirm_ps_per_candidate
-    if 0.25 <= ratio <= 4.0:
-        return
-    pricing = _replace(
-        eng._fdr_pricing, confirm_ps_per_candidate=measured
-    )
-    swap_fdr_plan(eng, pricing, reason=(
-        f"confirm probe {measured:.0f} ps/candidate vs priced "
-        f"{eng._fdr_pricing.confirm_ps_per_candidate:.0f}"
-    ))
-
-def swap_fdr_plan(eng, pricing, reason: str) -> None:
-    """Recompile the FDR model under `pricing`; adopt it if the check
-    plan actually changed (device tables re-upload lazily)."""
-    try:
-        model = compile_fdr(
-            eng._fdr_pats, ignore_case=eng.ignore_case, pricing=pricing
-        )
-    except FdrError as e:
-        # real pricing says the set is not worth filtering at all:
-        # same routing as the compile-time rejection
-        eng._route_native(
-            f"FDR retune ({reason}): set not filterable under "
-            f"measured pricing ({e})"
-        )
-        eng._fdr_pricing = pricing
-        # the engine no longer answers for its construction args (mode
-        # changed under measured pricing): the cross-job cache must not
-        # hand this corpus-specific verdict to the next job
-        from distributed_grep_tpu.ops.engine import invalidate_cached_engine
-
-        invalidate_cached_engine(eng)
-        return
-    old = [(b.m, b.checks) for b in eng.fdr.banks]
-    new = [(b.m, b.checks) for b in model.banks]
-    if old != new:
-        log.info(
-            "FDR plan retuned (%s): %s gathers -> %s",
-            reason,
-            sum(b.total_gathers for b in eng.fdr.banks),
-            sum(b.total_gathers for b in model.banks),
-        )
-        eng.fdr = model
-        eng._fdr_dev_tables = None
-        eng._fdr_ep_dev_tables = None
-        eng._model_gen += 1  # new plan = new kernel compile: re-grace
-        # model_gen bump = the cached entry's compiled model is stale for
-        # OTHER jobs (plan tuned under this corpus's measured candidate
-        # rates): evict so the next lookup recompiles from base pricing
-        from distributed_grep_tpu.ops.engine import invalidate_cached_engine
-
-        invalidate_cached_engine(eng)
-    eng._fdr_pricing = pricing
-
-def maybe_retune_fdr(eng, n_bytes: int) -> None:
-    """Self-calibration stage 2: after a scan with enough evidence,
-    replace the assumed fp bias and confirm cost with the MEASURED
-    values from engine.stats (real candidates, real confirm wall) and
-    retune the plan if the constants were >2.5x off.  Runs at most once
-    per engine; the measured constants subsume OVERLAP_RESIDUE's role
-    for plan choice (both legs are observed, not modeled)."""
-    from dataclasses import replace as _replace
-
-    if (
-        eng.mode != "fdr"
-        or eng._fdr_retuned
-        or _os.environ.get("DGREP_NO_CALIBRATE")
-        # mixed sets OR the pairset kernel's EXACT 1-byte matches into
-        # the candidate words, so stats["candidates"] no longer
-        # measures the FDR filter's false-positive rate — a frequent
-        # short member would read as a massively blown bias and swap
-        # in a garbage plan.  The init probe and chip-aware pricing
-        # still calibrate these engines; only the stats-based stage-2
-        # retune is disabled.
-        or eng._fdr_pairset is not None
-    ):
-        return
-    cands = eng.stats.get("candidates", 0)
-    conf_s = eng.stats.get("confirm_seconds", 0.0)
-    if cands < 10_000 or n_bytes < (1 << 23) or conf_s <= 0.0:
-        return  # not enough evidence for stable constants
-    eng._fdr_retuned = True
-    measured_bias = (cands / n_bytes) / max(eng.fdr.fp_per_byte, 1e-12)
-    # confirm_seconds is wall through the ACTUAL thread fan of this
-    # host (min(8, cpu)); convert to the single-thread constant, keep
-    # pricing against the DECLARED deployment thread count.  The
-    # memory-bound confirm scales sublinearly with threads, so ideal
-    # x actual_threads would overestimate the single-thread cost and
-    # bias the retune toward extra device gathers — measure the real
-    # speedup with a second ConfirmSet probe at the actual fan and use
-    # probe_1t/probe_Nt (== measured speedup <= N) as the factor.
-    actual_threads = min(8, _os.cpu_count() or 1)
-    speedup = float(actual_threads)
-    probe_1t = getattr(eng, "calibration", {}).get("confirm_probe_ps")
-    if actual_threads > 1 and probe_1t and eng._fdr_confirm is not None:
-        from distributed_grep_tpu.models.fdr import probe_confirm_ps
-
-        probe_nt = probe_confirm_ps(
-            eng._fdr_confirm, n_threads=actual_threads
-        )
-        if probe_nt > 0:
-            speedup = min(speedup, max(1.0, probe_1t / probe_nt))
-    measured_ps = conf_s / cands * 1e12 * speedup
-    pr = eng._fdr_pricing
-    bias_off = measured_bias / pr.fp_bias
-    ps_off = measured_ps / pr.confirm_ps_per_candidate
-    eng.calibration = {
-        **getattr(eng, "calibration", {}),
-        "measured_fp_bias": measured_bias,
-        "measured_confirm_ps": measured_ps,
-    }
-    if 0.4 <= bias_off <= 2.5 and 0.4 <= ps_off <= 2.5:
-        return  # priced within tolerance: keep the plan
-    pricing = _replace(
-        pr,
-        fp_bias=max(measured_bias, 0.5),
-        confirm_ps_per_candidate=measured_ps,
-    )
-    swap_fdr_plan(eng, pricing, reason=(
-        f"measured bias {measured_bias:.2f} (priced {pr.fp_bias:.2f}), "
-        f"confirm {measured_ps:.0f} ps (priced "
-        f"{pr.confirm_ps_per_candidate:.0f})"
-    ))
-
-
-
 # jax.monitoring's event for one backend compile (a persistent-cache load
 # is not one).
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -322,22 +175,6 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
         and pallas_ok
         and pallas_scan.eligible(eng.shift_and)
     )
-    # SWAR packed variant (round 6, DGREP_SWAR=1): 4 stripes per u32 lane
-    # element for byte-sized automata with equality-only classes.  BOTH
-    # the full model and the rare-class filter must be eligible — the
-    # mid-scan defeat guard swaps filtered -> full without re-planning
-    # the (packed) segment layout.  Mesh mode keeps the unpacked kernel
-    # (sharded_kernels has no packed wiring yet).
-    use_swar = (
-        use_pallas_sa
-        and eng.mesh is None
-        and pallas_scan.swar_enabled()
-        and pallas_scan.swar_eligible(eng.shift_and)
-        and (eng._sa_filtered is None
-             or pallas_scan.swar_eligible(eng._sa_filtered))
-    )
-    if use_swar:
-        st["swar"] = 1
     # NFA mode without a real TPU (or over budget) falls back to the XLA
     # DFA path — same tables, interpreter-free.
     use_pallas_nfa = (
@@ -435,13 +272,7 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
     # the corpus-cache variant signature below — one source, so the cache
     # key can never drift from the layout the scan actually packs under.
     if use_pallas:
-        if use_mesh:
-            lane_mult = mesh_mult
-        elif use_swar:
-            # packed lanes tile in 4096-u32 blocks = 16384 stripes
-            lane_mult = pallas_scan.SWAR_LANES_PER_BLOCK
-        else:
-            lane_mult = pallas_scan.LANES_PER_BLOCK
+        lane_mult = mesh_mult if use_mesh else pallas_scan.LANES_PER_BLOCK
         lay_kwargs = dict(
             target_lanes=max(eng.target_lanes, lane_mult),
             min_chunk=512,
@@ -567,25 +398,18 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
         # the plane lives instead of copying it to the default device.
         ctx = jax.default_device(dev) if dev is not None else nullcontext()
         with ctx:
-            if sparse_kind in ("span_words", "span_words_packed"):
+            if sparse_kind == "span_words":
                 # Coarse shift-and: nonzero words name 32-byte spans
                 # that contain >= 1 candidate match end (exact at span
                 # granularity for the full model; a superset when the
                 # rare-class filter ran).  Map spans to their
                 # overlapping lines, confirm each line once on host —
-                # overlapped with the next segment's device scan.  The
-                # SWAR variant packs 4 stripes per word; its decoder
-                # demuxes byte-plane flags to the same span starts.
+                # overlapped with the next segment's device scan.
                 idx, vals = fetch(payload)
                 with spans_mod.span("engine:decode", cat="engine"):
-                    if sparse_kind == "span_words_packed":
-                        starts = sparse_mod.span_starts_from_packed_words(
-                            idx, vals, lay
-                        )
-                    else:
-                        starts = sparse_mod.span_starts_from_sparse_words(
-                            idx, lay
-                        )
+                    starts = sparse_mod.span_starts_from_sparse_words(
+                        idx, lay
+                    )
                     if starts.size:
                         g0 = starts + seg_start  # global span starts
                         g1 = np.minimum(g0 + 32, len(data))
@@ -858,18 +682,15 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
             if seg_start > 0:
                 boundaries.append(seg_start)
             # Every kernel below jit-specializes on the padded layout
-            # shape (+ the plan constants, _model_gen): a key this
-            # engine has not completed a dispatch for may block on a
+            # shape (+ the plan constants, fixed at construction): a key
+            # this engine has not completed a dispatch for may block on a
             # fresh ~20-40 s compile with no observable progress, so
             # declare a grace window first.  Marked done only AFTER the
             # kernel call returns — a concurrent scan blocked on the
             # same compile still declares its own grace.  (The mid-scan
-            # defeat guards swap models without bumping _model_gen;
-            # their rare recompile risks one spurious sweep, accepted.)
-            compile_key = (
-                eng.mode, use_mesh, eng._model_gen,
-                getattr(arr, "shape", None),
-            )
+            # defeat guards swap models without changing the key; their
+            # rare recompile risks one spurious sweep, accepted.)
+            compile_key = (eng.mode, use_mesh, getattr(arr, "shape", None))
             if progress is not None and compile_key not in eng._compiled_keys:
                 if supports_grace:
                     progress(grace_s=_engine_mod.COMPILE_GRACE_S)
@@ -951,19 +772,12 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
                                 coarse=True, interpret=interp_flag,
                             )
                             psum_totals.append(pt)
-                            kind = "span_words"
-                        elif use_swar:
-                            words = pallas_scan.swar_shift_and_scan_words(
-                                arr, sa_filtered or eng.shift_and,
-                                interpret=interp_flag,
-                            )
-                            kind = "span_words_packed"
                         else:
                             words = pallas_scan.shift_and_scan_words(
                                 arr, sa_filtered or eng.shift_and,
                                 coarse=True, interpret=interp_flag,
                             )
-                            kind = "span_words"
+                        kind = "span_words"
                     elif use_pallas_approx:
                         if use_mesh:
                             words, pt = shk.sharded_approx_words(
@@ -1100,6 +914,5 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
         # cross-check dryrun_multichip asserts against the host count.
         st["psum_candidates"] = sum(int(t) for t in psum_totals)
     st["scan_wall_seconds"] = _time.perf_counter() - t_wall0
-    maybe_retune_fdr(eng, len(data))
     return ScanResult(lines_arr, int(lines_arr.size), len(data))
 

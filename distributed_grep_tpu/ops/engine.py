@@ -140,8 +140,8 @@ SPAN_CONFIRM_LINE_LIMIT = 4096
 # per task as jobs multiplex over shared workers; without a cache every
 # pattern re-pays model compile (AC banks, FDR plans, Glushkov builds)
 # and — on a real chip — the ~20-40 s first XLA/Mosaic compile per fresh
-# (mode, mesh, model_gen, shape) key.  cached_engine() memoizes whole
-# engines by their construction arguments: a cache hit returns the SAME
+# (mode, mesh, shape) key.  cached_engine() memoizes whole engines
+# by their construction arguments: a cache hit returns the SAME
 # engine object, so its _compiled_keys / jit caches / uploaded device
 # tables come along for free and the compile-grace path is skipped on
 # the repeat submit.  Engines are scan-thread-safe by construction
@@ -206,22 +206,6 @@ def model_cache_clear() -> None:
                 _model_cache_stats[k] = 0
 
 
-def invalidate_cached_engine(eng: "GrepEngine") -> None:
-    """Evict an engine whose compiled model changed underneath its cache
-    key — the FDR retune path (ops/device_scan.swap_fdr_plan) bumps
-    _model_gen when it adopts a recompiled plan, and that plan was tuned
-    under ONE corpus's measured candidate rates: the next job asking for
-    this pattern must start from the base pricing, not inherit another
-    corpus's calibration."""
-    with _model_cache_lock:
-        evicted = 0
-        for k in [k for k, v in _model_cache.items() if v is eng]:
-            del _model_cache[k]
-            evicted += 1
-    if evicted:
-        _count_cache("compile_cache_evictions", evicted)
-
-
 def _hashable(v):
     if isinstance(v, (list, tuple)):
         return tuple(_hashable(x) for x in v)
@@ -248,11 +232,17 @@ def cached_engine(
     while the symbolic devices="all" (the grep_tpu default) stays
     cacheable).
 
+    The cache relies on one invariant: an engine never changes its
+    plan, models or kernels after __init__ — they are a pure function of
+    the construction args, so one object can answer every job that asks
+    with the same key, concurrently.
+
     Construction runs UNDER the cache lock: two workers racing the same
     pattern serialize into one compile + one hit instead of two compiles
     (the whole point in the service regime); distinct-pattern
     constructions serialize too — the accepted cost, bounded by one
-    model compile."""
+    model compile.  The constructor never calls back into the cache, so
+    holding the (non-reentrant) lock across it cannot deadlock."""
     cap = env_model_cache_entries()
     key: tuple | None = (
         pattern,
@@ -446,7 +436,6 @@ class GrepEngine:
         # call returns (compile done), so concurrent scans blocked on the
         # same compile each declare their own grace.
         self._compiled_keys: set = set()
-        self._model_gen = 0  # bumped when a retune swaps kernel constants
         self._accel_cached: bool | None = None  # see _accel_backend
         # THREAD-LOCAL: one engine is scanned concurrently by worker slots
         # sharing the app module (grep_tpu), and a shared stash would let
@@ -466,9 +455,9 @@ class GrepEngine:
         # Backed per-THREAD (the stats property below): concurrent worker
         # slots each see the counters of their own scan; a shared dict let
         # one slot's `self.stats = {}` reset land under another slot's
-        # in-flight `+=` (crash or cross-scan telemetry bleed into the
-        # retune).  Within one scan, _scan_device captures its dict once
-        # so collect-pool threads mutate the owning scan's counters.
+        # in-flight `+=` (crash or cross-scan telemetry bleed).  Within
+        # one scan, _scan_device captures its dict once so collect-pool
+        # threads mutate the owning scan's counters.
         self._stats_local = _threading.local()
 
         # Hyperscan-style literal decomposition: a regex that denotes a
@@ -616,8 +605,7 @@ class GrepEngine:
                             # to hide behind.  Same ceiling as the FDR
                             # plan's own candidate-rate gate; the whole set
                             # then routes loudly to the native scanner
-                            # below (the retune that might notice later is
-                            # disabled for mixed sets by design).
+                            # below.
                             short_dens = expected_match_density(
                                 short_pats, ignore_case=ignore_case
                             )
@@ -670,13 +658,6 @@ class GrepEngine:
                             confirm_pats, ignore_case=ignore_case,
                         )
                         self.mode = "fdr"
-                        # Self-calibration stage 1 (VERDICT r2 item 3): a
-                        # ~ms single-thread ConfirmSet probe at init catches
-                        # order-of-magnitude mispricing (e.g. the Python-
-                        # fallback confirm without the native lib) and
-                        # recompiles the filter plan under measured pricing.
-                        self._fdr_pats = long_pats
-                        self._calibrate_fdr_confirm()
                     except FdrError as e:
                         log.info("pattern set FDR-ineligible: %s", e)
                 # FDR-ineligible sets (density over the candidate ceiling,
@@ -825,12 +806,12 @@ class GrepEngine:
             self.mode = "native"  # host C scanner, same tables
 
     def _route_native(self, why: str) -> bool:
-        """Loud device->host demotion (one policy, three callers: the
-        FDR-ineligible set branch, the single-pattern device-subset
-        branch, the FDR retune rejection): the native scanners give the
-        exact same answers off the AC/DFA tables at ~3-100x the XLA DFA
-        device path's ~0.1 GB/s.  No-op when the native lib is missing —
-        the device path, slow as it is, beats a Python table walk."""
+        """Loud device->host demotion (one policy, two callers: the
+        FDR-ineligible set branch and the single-pattern device-subset
+        branch): the native scanners give the exact same answers off the
+        AC/DFA tables at ~3-100x the XLA DFA device path's ~0.1 GB/s.
+        No-op when the native lib is missing — the device path, slow as
+        it is, beats a Python table walk."""
         from distributed_grep_tpu.utils.native import native_available
 
         if not native_available():
@@ -842,7 +823,7 @@ class GrepEngine:
         self.mode = "native"
         return True
 
-    # ------------------------------------------------- FDR self-calibration
+    # ------------------------------------------------------- FDR pricing
     def _active_chip_count(self) -> int:
         """Chips whose scan streams share this host's confirm threads.
 
@@ -883,30 +864,12 @@ class GrepEngine:
             pricing = _replace(pricing, n_chips=n_chips)
         return pricing
 
-    # ---------------------------------------------- FDR self-calibration
-    # (implementation in ops/device_scan.py — split out round 5; thin
-    # delegates keep the engine surface and test hooks unchanged)
-    def _calibrate_fdr_confirm(self) -> None:
-        from distributed_grep_tpu.ops.device_scan import calibrate_fdr_confirm
-
-        calibrate_fdr_confirm(self)
-
-    def _swap_fdr_plan(self, pricing, reason: str) -> None:
-        from distributed_grep_tpu.ops.device_scan import swap_fdr_plan
-
-        swap_fdr_plan(self, pricing, reason)
-
-    def _maybe_retune_fdr(self, n_bytes: int) -> None:
-        from distributed_grep_tpu.ops.device_scan import maybe_retune_fdr
-
-        maybe_retune_fdr(self, n_bytes)
-
     # ------------------------------------------------------------------ scan
     @property
     def stats(self) -> dict:
         """Per-thread scan counters: the thread that ran scan() reads its
-        own scan's numbers (bench/CLI/retune all read from the scanning
-        thread), and concurrent worker slots cannot clobber each other."""
+        own scan's numbers (bench/CLI read from the scanning thread), and
+        concurrent worker slots cannot clobber each other."""
         d = getattr(self._stats_local, "d", None)
         if d is None:
             d = {}
@@ -2172,8 +2135,7 @@ class GrepEngine:
 
     def _fdr_ep_tables(self, pattern_axis):
         """Stacked pattern-axis-sharded FDR tables, built + uploaded once
-        per plan (reset alongside _fdr_dev_tables on retune) — the EP
-        analogue of _fdr_device_tables."""
+        per engine — the EP analogue of _fdr_device_tables."""
         if self._fdr_ep_dev_tables is None:
             from distributed_grep_tpu.parallel import sharded_kernels as shk
 

@@ -312,8 +312,7 @@ class BatchPacker:
 # HOST bytes (the confirm/stitch pass and matched-line emit read them),
 # so the real footprint is ~2x the device budget; DGREP_CORPUS_BYTES
 # budgets the DEVICE-resident bytes and LRU-evicts whole entries beyond
-# it.  Pattern-dependent state never enters an entry — FDR retunes and
-# model-cache invalidations leave corpus entries alone by construction.
+# it.  Pattern-dependent state never enters an entry.
 
 # Default device budget when jax's default backend is a real accelerator
 # and neither DGREP_CORPUS_BYTES nor the engine's corpus_bytes= is set.

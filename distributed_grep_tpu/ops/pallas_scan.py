@@ -260,230 +260,6 @@ def shift_and_scan(
     return _unpack_words_to_lane_bits(np.asarray(words), chunk, lanes)
 
 
-# ------------------------------------------------------ SWAR packed variant
-#
-# Four stripes per u32 lane element: the (chunk, lanes) u8 corpus bitcasts
-# (free, on device) to (chunk, lanes//4) u32 where byte k of element j is
-# stripe 4j+k's byte at this chunk position.  Each stripe's automaton
-# lives in its own byte of the u32 state tile (SWAR_MAX_SYMBOLS: state +
-# match bit fit 8 bits), so one (32, 128) vector op advances 16384
-# stripes' automata — 4 corpus bytes per i32 lane element where the base
-# kernel moves one (the ALU roofline the round-6 probe targets).
-#
-# Per-byte-class hit detection is the EXACT SWAR zero-byte test on
-# y = x ^ (v * 0x01010101):
-#
-#   t  = y | ((y | 0x80808080) - 0x01010101)   # bit 7 of byte k clear
-#                                              # iff y's byte k == 0; no
-#                                              # cross-byte borrows (each
-#                                              # minuend byte >= 0x80)
-#   nz = ~t & 0x80808080                       # 0x80 flag per hit byte
-#
-# (NOT the classic Mycroft `(y - 1) & ~y & 0x80` form, whose borrows can
-# false-flag a byte after a hit — still a candidate superset, but the
-# probe's bit-exactness bar and the defeat guards want exact words.)
-# Flags become per-byte B-mask contributions borrow-free:
-#
-#   (nz - (nz >> 7)) & (mask * 0x01010101)     # 0x7F at hits, then mask
-#   nz & 0x80808080                            # bit-7 mask positions
-#
-# and the state step needs no cross-byte guard: the only leak of
-# `s << 1` lands on bit 0 of the next byte, which `| 0x01010101`
-# overwrites anyway.
-#
-# Output is COARSE only (the production literal path): one u32 word per
-# 32 byte-steps per PACKED lane, byte k's match bit = "a candidate match
-# ends in this 32-byte span of stripe 4j+k" — decode via
-# ops/sparse.span_starts_from_packed_words.
-
-SWAR_LANES_PER_BLOCK = 4 * LANES_PER_BLOCK  # corpus stripes per grid block
-# Packed u32 elements are 4x the bytes of the unpacked kernel's u8, so a
-# grid block takes a quarter of the byte-steps: the same 2 MiB input block
-# (CHUNK_BLOCK_WORDS here measured 16.5 MiB of scoped VMEM against the
-# v5e's 16 MiB limit in an ahead-of-time compile, tests/test_tpu_compile).
-SWAR_CHUNK_BLOCK_WORDS = CHUNK_BLOCK_WORDS // 4
-
-
-def swar_eligible(model: ShiftAndModel) -> bool:
-    from distributed_grep_tpu.models.shift_and import swar_values
-
-    return swar_values(model) is not None
-
-
-def swar_enabled() -> bool:
-    """DGREP_SWAR=1 routes eligible shift-and scans through the packed
-    kernel.  Default OFF: the variant is interpret-validated bit-exact
-    (tests/test_fuzz_swar.py) and op-count analysis predicts ~1.5x over
-    the unpacked coarse kernel, but it has never been timed on a chip;
-    flip the default only with a measured win."""
-    import os
-
-    return os.environ.get("DGREP_SWAR", "") == "1"
-
-
-def _swar_kernel(data_ref, out_ref, state_ref, *, sym_values, match_bit,
-                 steps, unroll=32):
-    from jax.experimental import pallas as pl  # deferred: import cost
-
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
-    def _init():
-        state_ref[:] = jnp.zeros_like(state_ref)
-
-    ONE = jnp.uint32(0x01010101)
-    HI = jnp.uint32(0x80808080)
-
-    # Symbols sharing a value set share one detection chain, exactly like
-    # the unpacked kernel's range groups; wildcards are a compile-time OR.
-    groups: dict[tuple, int] = {}
-    wildcard = 0
-    for j, vals in enumerate(sym_values):
-        if not vals:
-            wildcard |= 1 << j
-            continue
-        groups[vals] = groups.get(vals, 0) | (1 << j)
-    group_list = tuple(groups.items())
-    wild_rep = jnp.uint32(wildcard * 0x01010101)
-    match_rep = jnp.uint32(match_bit * 0x01010101)
-
-    n_inner = 32 // unroll
-
-    def word_body(w, carry):
-        def sub_body(sx, inner):
-            word, s = inner
-            for tt in range(unroll):
-                x = data_ref[w * 32 + sx * unroll + tt]
-                bmask = jnp.full((SUBLANES, LANE_COLS), wild_rep)
-                for vals, mask in group_list:
-                    t = None
-                    for v in vals:
-                        y = x ^ jnp.uint32(v * 0x01010101)
-                        tv = y | ((y | HI) - ONE)
-                        t = tv if t is None else (t & tv)  # OR of hits
-                    nz = ~t & HI
-                    m7f = mask & 0x7F
-                    if m7f:
-                        bmask = bmask | (
-                            (nz - (nz >> jnp.uint32(7)))
-                            & jnp.uint32(m7f * 0x01010101)
-                        )
-                    if mask & 0x80:
-                        bmask = bmask | nz  # bit-7 position: flags ARE it
-                s = ((s << jnp.uint32(1)) | ONE) & bmask
-                word = word | s
-            return word, s
-
-        word0 = jnp.zeros((SUBLANES, LANE_COLS), dtype=jnp.uint32)
-        if n_inner == 1:
-            word, s = sub_body(0, (word0, carry))
-        else:
-            word, s = jax.lax.fori_loop(0, n_inner, sub_body, (word0, carry))
-        out_ref[w] = word & match_rep
-        return s
-
-    final = jax.lax.fori_loop(0, steps // 32, word_body, state_ref[:])
-    state_ref[:] = final
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "sym_values", "match_bit", "chunk", "lane_blocks", "interpret",
-        "unroll",
-    ),
-)
-def _swar_pallas(data, *, sym_values, match_bit, chunk, lane_blocks,
-                 interpret=False, unroll=32):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    steps = 32 * SWAR_CHUNK_BLOCK_WORDS
-    chunk_blocks = chunk // steps
-    validate_unroll(unroll)
-    kernel = functools.partial(
-        _swar_kernel, sym_values=sym_values, match_bit=match_bit,
-        steps=steps, unroll=unroll,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(lane_blocks, chunk_blocks),
-        in_specs=[
-            pl.BlockSpec(
-                (steps, SUBLANES, LANE_COLS),
-                lambda li, ci: (ci, li, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (SWAR_CHUNK_BLOCK_WORDS, SUBLANES, LANE_COLS),
-            lambda li, ci: (ci, li, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (chunk // 32, lane_blocks * SUBLANES, LANE_COLS), jnp.uint32
-        ),
-        scratch_shapes=[pltpu.VMEM((SUBLANES, LANE_COLS), jnp.uint32)],
-        interpret=interpret,
-    )(data)
-
-
-def swar_pack_tiles(arr_cl, lane_blocks: int) -> jnp.ndarray:
-    """(chunk, lanes) u8 -> (chunk, lane_blocks*32, 128) u32 packed tiles:
-    element [t, ...] byte k = stripe 4j+k's byte t.  On an already-device
-    array this is a reshape + bitcast (free); host arrays pack via a
-    little-endian u32 view."""
-    chunk, lanes = arr_cl.shape
-    if isinstance(arr_cl, jnp.ndarray):
-        u32 = jax.lax.bitcast_convert_type(
-            arr_cl.reshape(chunk, lanes // 4, 4), jnp.uint32
-        )
-        return u32.reshape(chunk, lane_blocks * SUBLANES, LANE_COLS)
-    packed = np.ascontiguousarray(arr_cl).view("<u4")
-    return jnp.asarray(np.ascontiguousarray(
-        packed.reshape(chunk, lane_blocks * SUBLANES, LANE_COLS)
-    ))
-
-
-def swar_shift_and_scan_words(
-    arr_cl,
-    model: ShiftAndModel,
-    interpret: bool | None = None,
-    unroll: int = 32,
-) -> jnp.ndarray:
-    """Run the SWAR packed kernel; returns coarse words as a DEVICE array
-    (chunk//32, lane_blocks*32, 128) uint32 over PACKED lanes — byte k's
-    match bit of word [w, j] = candidate in stripe 4j+k's span w.  Decode
-    via ops/sparse.span_starts_from_packed_words and confirm lines (the
-    span_words contract).  Requires lanes % 16384 == 0, chunk % 512 == 0,
-    and a swar_values-eligible model."""
-    from distributed_grep_tpu.models.shift_and import swar_values
-
-    chunk, lanes = arr_cl.shape
-    steps = 32 * CHUNK_BLOCK_WORDS
-    if lanes % SWAR_LANES_PER_BLOCK or chunk % steps:
-        raise ValueError(
-            f"swar layout needs lanes%{SWAR_LANES_PER_BLOCK}==0, "
-            f"chunk%{steps}==0"
-        )
-    vals = swar_values(model)
-    if vals is None:
-        raise ValueError("pattern ineligible for the SWAR packed kernel")
-    lane_blocks = lanes // SWAR_LANES_PER_BLOCK
-    data = swar_pack_tiles(arr_cl, lane_blocks)
-    if interpret is None:
-        interpret = not available()
-    return _swar_pallas(
-        data,
-        sym_values=tuple(vals),
-        match_bit=int(model.match_bit),
-        chunk=chunk,
-        lane_blocks=lane_blocks,
-        interpret=interpret,
-        unroll=unroll,
-    )
-
-
 def _unpack_words_to_lane_bits(words: np.ndarray, chunk: int, lanes: int) -> np.ndarray:
     """Convert time-packed kernel words to the (chunk, lanes//8) lane-packed
     convention shared with scan_jnp (bit t of words[w, s, l] = match at

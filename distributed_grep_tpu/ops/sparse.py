@@ -12,7 +12,6 @@ from __future__ import annotations
 import numpy as np
 
 from distributed_grep_tpu.ops.layout import Layout
-from distributed_grep_tpu.ops.pallas_scan import LANE_COLS, LANES_PER_BLOCK, SUBLANES
 
 
 # The bit index of a uint32 power of two from one multiply and one shift
@@ -65,33 +64,6 @@ def span_starts_from_sparse_words(
         return np.zeros(0, dtype=np.int64)
     w, lane = _word_lanes(idx, layout)
     starts = lane * layout.chunk + w * 32
-    starts = starts[starts < layout.n_real]
-    starts.sort()
-    return starts.astype(np.int64)
-
-
-def span_starts_from_packed_words(
-    idx: np.ndarray, vals: np.ndarray, layout: Layout
-) -> np.ndarray:
-    """Decode the SWAR packed coarse output (pallas_scan
-    swar_shift_and_scan_words): words live on PACKED lanes (4 stripes per
-    u32), and byte k's match bit names a candidate 32-byte span of stripe
-    4j+k.  Returns sorted document offsets of span starts, exactly the
-    span_starts_from_sparse_words contract."""
-    if idx.size == 0:
-        return np.zeros(0, dtype=np.int64)
-    S = (layout.lanes // 4) // LANE_COLS
-    l = idx % LANE_COLS
-    rest = idx // LANE_COLS
-    s = rest % S
-    w = rest // S
-    j = (s // SUBLANES) * LANES_PER_BLOCK + (s % SUBLANES) * LANE_COLS + l
-    out = []
-    for k in range(4):
-        sel = (vals >> np.uint32(8 * k)) & np.uint32(0xFF) != 0
-        if sel.any():
-            out.append((4 * j[sel] + k) * layout.chunk + w[sel] * 32)
-    starts = np.concatenate(out) if out else np.zeros(0, dtype=np.int64)
     starts = starts[starts < layout.n_real]
     starts.sort()
     return starts.astype(np.int64)

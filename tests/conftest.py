@@ -150,8 +150,8 @@ def _fresh_model_cache():
     """The cross-job compiled-model cache (ops/engine.cached_engine) is
     process-global by design — in production a service process WANTS
     engines shared across jobs.  Across tests that sharing would leak
-    mutated engine state (forced _accel_cached, retuned FDR plans) from one test's engine into another's, so each test starts
-    and ends with an empty cache."""
+    mutated engine state (forced _accel_cached) from one test's engine
+    into another's, so each test starts and ends with an empty cache."""
     from distributed_grep_tpu.ops import engine as _eng
 
     _eng.model_cache_clear()

@@ -58,7 +58,6 @@ class ServiceProc:
         self.env = {
             "PYTHONPATH": REPO, "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
             "JAX_PLATFORMS": "cpu", "DGREP_LOG": "WARNING",
-            "DGREP_NO_CALIBRATE": "1",
             **(env or {}),
         }
         self.proc: subprocess.Popen | None = None

@@ -23,12 +23,6 @@ from distributed_grep_tpu.ops.engine import GrepEngine, ScanResult
 from distributed_grep_tpu.ops.layout import BatchPacker, packed_size
 
 
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    """Deterministic FDR plans (CLAUDE.md: DGREP_NO_CALIBRATE for CI)."""
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
 def _blobs() -> dict[str, bytes]:
     """Edge-case corpus: trailing-newline-less files, empty files, files of
     only empty lines, needles for every engine family."""

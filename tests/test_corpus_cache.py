@@ -31,12 +31,6 @@ from distributed_grep_tpu.ops.engine import GrepEngine
 BUDGET = 1 << 28  # roomy test budget: nothing evicts unless a test asks
 
 
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    """Deterministic FDR plans (CLAUDE.md: DGREP_NO_CALIBRATE for CI)."""
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
 def _corpus_bytes_fixture() -> bytes:
     """Needles for every engine family under test, plus hay."""
     rng = np.random.default_rng(13)

@@ -381,121 +381,132 @@ def test_unfactorizable_short_set_still_routes_to_native():
     assert eng.mode == "native"
 
 
-# ------------------------------------ tuner self-calibration (round 3)
+# ------------------------------------ plan fixed at construction
 
-def test_probe_recovers_from_poisoned_confirm_constant(monkeypatch):
-    """Inject an absurd priced confirm cost ('confirm is free'); the init
-    probe must measure the real cost and retune the plan back toward the
-    honestly-priced one (VERDICT r2 item 3 done-criterion)."""
-    import distributed_grep_tpu.models.fdr as fdr_mod
+ALNUM = b"abcdefghijklmnopqrstuvwxyz0123456789"
+
+
+def _plan(model):
+    return [(b.m, b.checks) for b in model.banks]
+
+
+def _slow_host_confirm(monkeypatch):
+    """A host confirm ~100x slower than the priced constant: a plan that
+    read this host's confirm speed would come out different."""
+    import time
+
+    from distributed_grep_tpu.utils.native import ConfirmSet
+
+    real = ConfirmSet.confirm
+
+    def slow(self, data, ends, n_threads=None):
+        time.sleep(0.05)
+        return real(self, data, ends, n_threads=n_threads)
+
+    monkeypatch.setattr(ConfirmSet, "confirm", slow)
+
+
+FIXED_PLAN_SETS = {
+    "plain": ([p.decode() for p in _rand_literals(300, 5, 8, 31, ALNUM)],
+              False),
+    "ignore_case": ([p.decode().upper() if i % 2 else p.decode()
+                     for i, p in enumerate(_rand_literals(300, 5, 8, 32))],
+                    True),
+    "mixed_short": ([p.decode() for p in _rand_literals(60, 4, 8, seed=14)]
+                    + ["!", "~"], False),
+}
+
+
+@pytest.mark.parametrize("name", list(FIXED_PLAN_SETS))
+def test_fdr_plan_does_not_depend_on_host_confirm_speed(monkeypatch, name):
+    """The engine's FDR plan is the one its construction args price —
+    base pricing at its chip count — however fast this host confirms."""
     from distributed_grep_tpu.ops.engine import GrepEngine
 
-    rng = np.random.default_rng(5)
-    alphabet = list(b"abcdefghijklmnopqrstuvwxyz0123456789")
-    pats = sorted({
-        bytes(rng.choice(alphabet, size=int(rng.integers(5, 9))).tolist())
-        for _ in range(3000)
-    })
-    spats = [p.decode() for p in pats]
-
-    monkeypatch.setattr(fdr_mod, "CONFIRM_PS_PER_CANDIDATE", 1.0)
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-    eng_bad = GrepEngine(patterns=spats)
-    g_bad = sum(b.total_gathers for b in eng_bad.fdr.banks)
-
-    monkeypatch.delenv("DGREP_NO_CALIBRATE")
-    # pin the probe's measurement (real timing is load-dependent; the
-    # wiring probe->mismatch->retune is what's under test)
-    import distributed_grep_tpu.ops.engine as engine_mod  # noqa: F401
-    monkeypatch.setattr(fdr_mod, "probe_confirm_ps", lambda cs, **kw: 8600.0)
-    eng_fix = GrepEngine(patterns=spats)
-    g_fix = sum(b.total_gathers for b in eng_fix.fdr.banks)
-    assert eng_fix.calibration["confirm_probe_ps"] == 8600.0
-    # probe-calibrated plan buys more device gathers than the 'free
-    # confirm' plan, converging toward the honest plan
-    assert g_fix > g_bad
-    # and it equals a plan compiled directly under the measured pricing
-    from dataclasses import replace
-
-    pricing = replace(
-        fdr_mod.default_pricing(), confirm_ps_per_candidate=8600.0
+    pats, ignore_case = FIXED_PLAN_SETS[name]
+    _slow_host_confirm(monkeypatch)
+    eng = GrepEngine(patterns=pats, ignore_case=ignore_case)
+    assert eng.mode == "fdr"
+    assert (eng._fdr_pairset is not None) == (name == "mixed_short")
+    direct = fdr_mod.compile_fdr(
+        [p for p in pats if len(p) >= 2], ignore_case=ignore_case,
+        pricing=fdr_mod.default_pricing(),
     )
-    direct = fdr_mod.compile_fdr(spats, pricing=pricing)
-    assert [(b.m, b.checks) for b in eng_fix.fdr.banks] == \
-        [(b.m, b.checks) for b in direct.banks]
+    assert _plan(eng.fdr) == _plan(direct)
 
 
-def test_post_scan_retune_from_measured_stats():
-    """Stage-2 retune: measured candidate rate and confirm wall far off the
-    priced constants must swap in a plan compiled under measured pricing."""
-    import os
+@pytest.mark.parametrize("distinct", [False, True],
+                         ids=["same_set", "distinct_sets"])
+def test_fdr_engines_build_concurrently_through_the_model_cache(
+    monkeypatch, distinct
+):
+    """Four threads build FDR engines through the cross-job model cache
+    at once, on a host whose confirm is far off the priced constant:
+    every build returns (the constructor never calls back into the cache
+    whose lock it runs under), and one key gives one object and one
+    miss."""
+    import threading
 
-    import distributed_grep_tpu.models.fdr as fdr_mod
-    from distributed_grep_tpu.ops.engine import GrepEngine
+    from distributed_grep_tpu.ops import engine as engine_mod
 
-    rng = np.random.default_rng(6)
-    alphabet = list(b"abcdefghijklmnopqrstuvwxyz0123456789")
-    pats = sorted({
-        bytes(rng.choice(alphabet, size=int(rng.integers(5, 9))).tolist())
-        for _ in range(3000)
-    })
-    eng = GrepEngine(patterns=[p.decode() for p in pats])
-    g0 = sum(b.total_gathers for b in eng.fdr.banks)
-    # manufacture evidence: candidates 20x the plan's expectation and a
-    # very slow confirm -> the retune must buy more gathers
-    n_bytes = 64 * 1024 * 1024
-    fake_cands = int(eng.fdr.fp_per_byte * 20 * n_bytes)
-    actual_threads = min(8, os.cpu_count() or 1)
-    eng.stats = {
-        "candidates": fake_cands,
-        # 400 ns wall per candidate through the actual fan
-        "confirm_seconds": fake_cands * 400e-9,
-    }
-    eng._maybe_retune_fdr(n_bytes)
-    assert eng._fdr_retuned
-    g1 = sum(b.total_gathers for b in eng.fdr.banks)
-    assert g1 > g0  # slow+dense confirm evidence -> more filtering on device
-    assert eng.calibration["measured_fp_bias"] == pytest.approx(20.0, rel=0.01)
+    _slow_host_confirm(monkeypatch)
+    sets = [
+        [p.decode() for p in
+         _rand_literals(200, 5, 8, 40 + (i if distinct else 0), ALNUM)]
+        for i in range(4)
+    ]
+    got: list = [None] * 4
 
-    # within-tolerance evidence must NOT retune (runs-once flag aside)
-    eng2 = GrepEngine(patterns=[p.decode() for p in pats])
-    plan2 = [(b.m, b.checks) for b in eng2.fdr.banks]
-    pr = eng2._fdr_pricing
-    cands2 = int(eng2.fdr.fp_per_byte * pr.fp_bias * n_bytes)
-    eng2.stats = {
-        "candidates": cands2,
-        "confirm_seconds": cands2 * (pr.confirm_ps_per_candidate / 1e12)
-        / actual_threads * actual_threads,
-    }
-    eng2._maybe_retune_fdr(n_bytes)
-    assert [(b.m, b.checks) for b in eng2.fdr.banks] == plan2
+    def build(i):
+        got[i] = engine_mod.cached_engine(patterns=sets[i])
+
+    threads = [threading.Thread(target=build, args=(i,), daemon=True)
+               for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive(), "an engine build never returned"
+    engines = [e for e, _ in got]
+    assert all(e.mode == "fdr" for e in engines)
+    counters = engine_mod.model_cache_counters()
+    if distinct:
+        assert len({id(e) for e in engines}) == 4
+        assert sorted(v for _, v in got) == ["miss"] * 4
+        assert counters["compile_cache_misses"] == 4
+        assert counters["compile_cache_hits"] == 0
+    else:
+        assert len({id(e) for e in engines}) == 1
+        assert sorted(v for _, v in got) == ["hit", "hit", "hit", "miss"]
+        assert counters["compile_cache_misses"] == 1
+        assert counters["compile_cache_hits"] == 3
 
 
-def test_scan_stays_exact_after_retune_swap():
-    """After the stage-2 retune swaps the FDR plan, the next scan must
-    re-upload the new bank tables and stay exact (pins the
-    _fdr_dev_tables reset path)."""
-    import os
+@pytest.mark.parametrize("n_chips", [1, 8])
+def test_fdr_scan_exact_under_chip_count_pricing(n_chips):
+    """The plan priced for one chip and the one priced for the 8-chip
+    confirm share (an engine driving every local device) both scan
+    exactly: the engine runs the plan its chip count prices, and its
+    lines equal the reference."""
+    from dataclasses import replace
 
     from distributed_grep_tpu.ops.engine import GrepEngine
 
     rng = np.random.default_rng(12)
-    alphabet = list(b"abcdefghijklmnopqrstuvwxyz0123456789")
+    alphabet = list(ALNUM)
     pats = sorted({
         bytes(rng.choice(alphabet, size=int(rng.integers(5, 9))).tolist())
         for _ in range(2000)
     })
-    eng = GrepEngine(patterns=[p.decode() for p in pats], interpret=True)
+    spats = [p.decode() for p in pats]
+    eng = GrepEngine(patterns=spats, interpret=True,
+                     devices="all" if n_chips > 1 else None)
     assert eng.mode == "fdr"
-    plan0 = [(b.m, b.checks) for b in eng.fdr.banks]
-
-    n_bytes = 64 * 1024 * 1024
-    fake = int(eng.fdr.fp_per_byte * 20 * n_bytes)
-    eng.stats = {"candidates": fake, "confirm_seconds": fake * 400e-9}
-    eng._maybe_retune_fdr(n_bytes)
-    assert eng._fdr_retuned
-    assert [(b.m, b.checks) for b in eng.fdr.banks] != plan0  # plan swapped
-    assert eng._fdr_dev_tables is None  # tables re-upload lazily
+    assert eng._fdr_base_pricing().n_chips == n_chips
+    direct = fdr_mod.compile_fdr(
+        spats, pricing=replace(fdr_mod.default_pricing(), n_chips=n_chips)
+    )
+    assert _plan(eng.fdr) == _plan(direct)
 
     lines = []
     for i in range(400):
@@ -508,8 +519,8 @@ def test_scan_stays_exact_after_retune_swap():
         i for i, ln in enumerate(data.split(b"\n")[:-1], 1)
         if any(p in ln for p in pats)
     }
-    got = set(eng.scan(data).matched_lines.tolist())
-    assert got == expected
+    assert expected
+    assert set(eng.scan(data).matched_lines.tolist()) == expected
 
 
 def test_chip_aware_pricing_buys_more_filtering():
@@ -542,15 +553,14 @@ def test_chip_aware_pricing_buys_more_filtering():
     )
 
 
-def test_engine_mesh_chip_count_pricing(monkeypatch):
+def test_engine_mesh_chip_count_pricing():
     """An engine driving an 8-device mesh must price the FDR confirm leg
-    at the 8-chip share from construction (not only after a retune)."""
+    at the 8-chip share from construction."""
     from dataclasses import replace
 
     from distributed_grep_tpu.ops.engine import GrepEngine
     from distributed_grep_tpu.parallel.mesh import make_mesh
 
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")  # pin: no probe swap
     pats = _rand_literals(
         3000, 5, 8, seed=21, alphabet=b"abcdefghijklmnopqrstuvwxyz0123456789"
     )
@@ -558,8 +568,8 @@ def test_engine_mesh_chip_count_pricing(monkeypatch):
     eng1 = GrepEngine(patterns=spats, interpret=True)
     mesh = make_mesh((8,), ("data",))
     eng8 = GrepEngine(patterns=spats, mesh=mesh, interpret=True)
-    assert eng1._fdr_pricing.n_chips == 1
-    assert eng8._fdr_pricing.n_chips == 8
+    assert eng1._fdr_base_pricing().n_chips == 1
+    assert eng8._fdr_base_pricing().n_chips == 8
     direct = fdr_mod.compile_fdr(
         spats, pricing=replace(fdr_mod.default_pricing(), n_chips=8)
     )
@@ -571,7 +581,7 @@ def test_engine_mesh_chip_count_pricing(monkeypatch):
         patterns=spats, mesh=mesh2, mesh_axis="data", pattern_axis="seq",
         interpret=True,
     )
-    assert eng_ep._fdr_pricing.n_chips == 8
+    assert eng_ep._fdr_base_pricing().n_chips == 8
 
 
 def test_pipeline_compression_preserves_candidates():
@@ -683,12 +693,6 @@ def test_mixed_set_short_members_ride_the_device():
     # the MESH kernels actually ran
     assert eng_m.stats.get("psum_candidates", 0) >= 1
     assert eng_m.stats["kernel"] == "pallas"
-    # and the stats-based retune is disabled for mixed sets (exact pairset
-    # matches pollute the candidate-rate measurement)
-    eng_m.stats["candidates"] = 10_000_000
-    eng_m.stats["confirm_seconds"] = 1.0
-    eng_m._maybe_retune_fdr(1 << 26)
-    assert not eng_m._fdr_retuned
 
 
 def test_mixed_set_dense_short_member_routes_to_native():

@@ -31,11 +31,6 @@ from distributed_grep_tpu.utils.config import JobConfig
 pytestmark = pytest.mark.follow
 
 
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
 # ---------------------------------------------------------------- oracle
 def _oracle(engine_kw: dict, data: bytes) -> list[tuple[int, bytes]]:
     """(line_no, line_bytes) a ONE-SHOT scan of the final file state

@@ -33,11 +33,6 @@ from distributed_grep_tpu.utils.config import JobConfig
 pytestmark = pytest.mark.follow
 
 
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
 # ---------------------------------------------------------------- helpers
 def _mk_cfg(path, work_dir: str, **opts) -> JobConfig:
     app_options = {"backend": "cpu", **opts}

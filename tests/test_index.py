@@ -35,11 +35,6 @@ pytestmark = pytest.mark.index
 
 
 @pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
-@pytest.fixture(autouse=True)
 def _engine_store(tmp_path):
     """Engine-level summary BUILDS are gated on a reuse surface (an
     attached store, or corpus-cache opt-in — one-shot CLI jobs build

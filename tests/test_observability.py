@@ -222,10 +222,16 @@ def test_engine_spans_nest_under_the_scan():
     children of its scan:<mode> span, which nests under the worker's
     map:compute; its compile counter reads the compiles of the first
     scan and none on the repeat."""
+    import jax
+
     from distributed_grep_tpu.ops.engine import GrepEngine
 
-    eng = GrepEngine("needle", backend="device", interpret=True)
-    data = b"hay\nneedle here\nhay\n" * 200
+    # The first scan must compile whatever tests ran before in this
+    # process: drop jax's in-memory caches, and scan for a pattern no
+    # other test compiles a kernel for.
+    jax.clear_caches()
+    eng = GrepEngine("spindle", backend="device", interpret=True)
+    data = b"hay\nspindle here\nhay\n" * 200
     buf = spans.SpanBuffer()
     with spans.task_context(buf, job="j", worker=0, task=3, attempt="a",
                             kind="map"):

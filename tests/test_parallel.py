@@ -468,7 +468,6 @@ def test_confirms_overlap_across_device_segments(monkeypatch):
             lines[-1] = b"xx " + pats[int(rng.integers(0, len(pats)))] + b" yy"
     data = b"\n".join(lines) + b"\n"
 
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
     eng = GrepEngine(
         patterns=[p.decode() for p in pats], devices="all", interpret=True,
         segment_bytes=16 * 1024,

@@ -54,11 +54,6 @@ from distributed_grep_tpu.utils.config import JobConfig
 pytestmark = pytest.mark.service
 
 
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
-
-
 def outputs_by_name(paths) -> dict[str, bytes]:
     out = {}
     for p in paths:

@@ -146,18 +146,16 @@ def straddle_corpus(seed: int, hit: bytes, inject: dict[int, bytes]) -> bytes:
 
 ROUTES = [
     # (kind, pattern or literal set, regex for the reference, engine
-    #  kwargs, env, the decoder the route calls)
-    ("span_words", "needle", rb"needle", dict(interpret=True), {},
+    #  kwargs, the decoder the route calls)
+    ("span_words", "needle", rb"needle", dict(interpret=True),
      "span_starts_from_sparse_words"),
-    ("span_words_packed", "needle", rb"needle", dict(interpret=True),
-     {"DGREP_SWAR": "1"}, "span_starts_from_packed_words"),
-    ("cand_words", "Carol foo$", rb"Carol foo$", dict(interpret=True), {},
+    ("cand_words", "Carol foo$", rb"Carol foo$", dict(interpret=True),
      "offsets_from_sparse_words"),
     ("words", ["Alice", "Bob", "Erin"], rb"Alice|Bob|Erin",
-     dict(interpret=True), {}, "offsets_from_sparse_words"),
-    ("lane_bytes", "needle", rb"needle", {}, {},
+     dict(interpret=True), "offsets_from_sparse_words"),
+    ("lane_bytes", "needle", rb"needle", {},
      "offsets_from_sparse_lane_bytes"),
-    ("bank_list", r"\w+\s+Carol\s+\w+", rb"\w+\s+Carol\s+\w+", {}, {},
+    ("bank_list", r"\w+\s+Carol\s+\w+", rb"\w+\s+Carol\s+\w+", {},
      "offsets_from_sparse_lane_bytes"),
 ]
 HIT = b"Alice needle Carol foo"  # every route's pattern, '$' included
@@ -174,13 +172,11 @@ def engine_for(pattern, kwargs, segment_bytes):
 
 
 @pytest.mark.parametrize("case", ["one_segment", "multi_segment", "no_match"])
-@pytest.mark.parametrize("kind,pattern,regex,kwargs,env,decoder", ROUTES,
+@pytest.mark.parametrize("kind,pattern,regex,kwargs,decoder", ROUTES,
                          ids=[r[0] for r in ROUTES])
 def test_route_lines_equal_set_reference(
-    kind, pattern, regex, kwargs, env, decoder, case, monkeypatch
+    kind, pattern, regex, kwargs, decoder, case, monkeypatch
 ):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
     called = []
     real = getattr(sparse_mod, decoder)
 
@@ -197,8 +193,6 @@ def test_route_lines_equal_set_reference(
                      SEG if case == "multi_segment" else 1 << 20)
     res = eng.scan(data)
     assert called, f"the {kind} route did not run"
-    if kind == "span_words_packed":
-        assert eng.stats.get("swar") == 1
     if kind in ("lane_bytes", "bank_list"):
         assert eng.stats["kernel"] == "xla"
         assert eng.mode == ("shift_and" if kind == "lane_bytes" else "nfa")

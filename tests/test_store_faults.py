@@ -11,7 +11,7 @@ torn-tail handling.
 
 Standalone: ``python -m pytest tests/test_store_faults.py -q``.  CPU-only
 and device-free by construction — the plain grep app never touches a
-backend, and DGREP_NO_CALIBRATE keeps any engine construction inert.
+backend.
 """
 
 import io
@@ -40,14 +40,6 @@ from distributed_grep_tpu.utils.io import WorkDir
 pytestmark = pytest.mark.faults
 
 STORES = ["posix", "nonatomic"]
-
-
-@pytest.fixture(autouse=True)
-def _no_calibrate(monkeypatch):
-    """Deterministic, device-free matrix runs: no engine self-calibration
-    probes.  Scoped per test (an import-time os.environ write would leak
-    into every other module collected in the same pytest process)."""
-    monkeypatch.setenv("DGREP_NO_CALIBRATE", "1")
 
 
 def make_config(tmp_path, corpus, sub="job", **kw):

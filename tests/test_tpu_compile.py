@@ -58,14 +58,14 @@ def _layout(lane_multiple: int):
     )
 
 
-def _tiles(sharding, lane_multiple: int = 4096, dtype=np.uint8, pack=1):
+def _tiles(sharding):
     import jax
 
-    from distributed_grep_tpu.ops.pallas_scan import LANE_COLS
+    from distributed_grep_tpu.ops.pallas_scan import LANE_COLS, LANES_PER_BLOCK
 
-    lay = _layout(lane_multiple)
-    shape = (lay.chunk, lay.lanes // LANE_COLS // pack, LANE_COLS)
-    return lay, jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    lay = _layout(LANES_PER_BLOCK)
+    shape = (lay.chunk, lay.lanes // LANE_COLS, LANE_COLS)
+    return lay, jax.ShapeDtypeStruct(shape, np.uint8, sharding=sharding)
 
 
 def _const(arr, sharding):
@@ -119,23 +119,6 @@ def test_shift_and_compiles(one_chip, coarse):
         match_bit=int(model.match_bit), chunk=lay.chunk,
         lane_blocks=lay.lanes // pallas_scan.LANES_PER_BLOCK,
         interpret=False, coarse=coarse))
-
-
-def test_swar_shift_and_compiles(one_chip):
-    import jax.numpy as jnp
-
-    from distributed_grep_tpu.models.shift_and import swar_values
-    from distributed_grep_tpu.ops import pallas_scan
-
-    model = _engine("volcano").shift_and
-    assert pallas_scan.swar_eligible(model)
-    lay, x = _tiles(one_chip, pallas_scan.SWAR_LANES_PER_BLOCK,
-                    dtype=jnp.uint32, pack=4)
-    _assert_kernel(pallas_scan._swar_pallas.lower(
-        x, sym_values=tuple(swar_values(model)),
-        match_bit=int(model.match_bit), chunk=lay.chunk,
-        lane_blocks=lay.lanes // pallas_scan.SWAR_LANES_PER_BLOCK,
-        interpret=False))
 
 
 @pytest.mark.parametrize("gather_b", [False, True],
