@@ -48,8 +48,10 @@ EVENTS: dict[str, Event] = {
         "scan (the engine:* spans are its children); the member is the "
         "kernel family that ran (scan:batch = one packed cross-file flush); "
         "args.compiles = backend compiles the scan waited on; device scans "
-        "add args.stitch_lines (boundary lines re-matched on the host) and "
-        "args.host_line_passes (host matcher passes, stitch and confirm).",
+        "add args.stitch_lines (boundary lines re-matched on the host), "
+        "args.host_line_passes (host matcher passes, stitch and confirm), "
+        "args.fetch_blocks (nonzero result blocks fetched) and "
+        "args.fetch_overflows (result planes downloaded whole).",
         members=("re", "native", "dfa", "nfa", "shift_and", "fdr",
                  "pairset", "approx", "batch"),
     ),
@@ -62,7 +64,7 @@ EVENTS: dict[str, Event] = {
     "engine:wait": Event(
         ("span",), "engine", ("ops/device_scan.py",), (),
         "Host blocked on the device: kernel dispatch (and any compile) and "
-        "the sparse result fetch (count + gather + transfer).",
+        "the sparse result fetch (block flags + gather + one transfer).",
     ),
     "engine:decode": Event(
         ("span",), "engine", ("ops/device_scan.py",), (),

@@ -134,7 +134,8 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
 
     t_wall0 = _time.perf_counter()
     eng.stats = {"candidates": 0, "confirm_seconds": 0.0, "end_offsets": 0,
-                 "stitch_lines": 0, "host_line_passes": 0}
+                 "stitch_lines": 0, "host_line_passes": 0,
+                 "fetch_blocks": 0, "fetch_overflows": 0}
     # the ONE dict for this scan: collect()/prepare() run in pool
     # threads, where `eng.stats` would resolve to the POOL thread's
     # slot — references below go through this capture (except after a
@@ -320,9 +321,15 @@ def scan_device(eng, data: bytes, progress=None, corpus_key=None) -> ScanResult:
 
     def fetch(payload) -> tuple[np.ndarray, np.ndarray]:
         """The sparse fetch of one result plane: the host blocks here
-        until the segment's kernels are done (engine:wait)."""
+        until the segment's kernels are done (engine:wait).  Its block
+        counters land in the scan's stats."""
+        counts: dict = {}
         with spans_mod.span("engine:wait", cat="engine"):
-            return scan_jnp.sparse_nonzero(payload)
+            got = scan_jnp.sparse_nonzero(payload, counts)
+        with state_lock:
+            for k, v in counts.items():
+                st[k] += v
+        return got
 
     def host_lines_matching(data_, nl_, line_nos: np.ndarray) -> np.ndarray:
         """eng._host_lines_matching, its passes counted into this scan's

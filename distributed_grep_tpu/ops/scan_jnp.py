@@ -185,30 +185,56 @@ def shift_and_scan(data_cl: np.ndarray, model: ShiftAndModel) -> jnp.ndarray:
 
 
 # ----------------------------------------------------- sparse result fetch
-# grep matches are sparse, and the host<->device link is the narrow
-# pipe.  Instead of downloading the
-# dense packed-bit plane (input/8 bytes), count the nonzero packed bytes on
-# device (4-byte transfer), then gather exactly those bytes + their indices
-# (a few KB for realistic match densities).
-
+# grep matches are sparse, and the host<->device link is the narrow pipe.
+# Instead of downloading the dense packed-bit plane (input/8 bytes), the
+# device views the flat plane as blocks of FETCH_BLOCK elements, flags the
+# blocks that hold a nonzero element, and gathers up to FETCH_CAP of them:
+# the count, their block ids and their elements come back in ONE transfer
+# (no count first, no scatter over the plane).  A plane with more nonzero
+# blocks than FETCH_CAP is downloaded whole instead; the host decodes
+# either with one np.flatnonzero.
 
 # Each XLA-side step of a scan is a jitted function of its own with a
 # named scope, so a profiler trace names it (its XLA module and its ops'
 # metadata) instead of an anonymous eager op.
 
+FETCH_BLOCK = 128  # elements a block (a power of two)
+FETCH_CAP = 1024  # nonzero blocks one fetch brings back
+
+
+def _fetch_shape(size: int) -> tuple[int, int]:
+    """(blocks in a plane of ``size`` elements, blocks a fetch returns)."""
+    n_blocks = -(-size // FETCH_BLOCK)
+    return n_blocks, min(FETCH_CAP, n_blocks)
+
 
 @jax.jit
-def count_nonzero_bytes(packed: jnp.ndarray) -> jnp.ndarray:
-    with jax.named_scope("count_nonzero_bytes"):
-        return jnp.count_nonzero(packed)
-
-
-@partial(jax.jit, static_argnames=("k",))
-def gather_nonzero_bytes(packed: jnp.ndarray, k: int):
-    with jax.named_scope("gather_nonzero_bytes"):
+def fetch_nonzero_blocks(packed: jnp.ndarray) -> jnp.ndarray:
+    """One uint32 vector: the plane's count of nonzero blocks, the ids of
+    the first ``cap`` of them (ascending; past the count, ``n_blocks``),
+    then those blocks' elements, ``4 // itemsize`` to a word,
+    little-endian (``packed``: unsigned, 1, 2 or 4 bytes)."""
+    with jax.named_scope("fetch_nonzero_blocks"):
         flat = packed.reshape(-1)
-        idx = jnp.nonzero(flat, size=k, fill_value=0)[0]
-        return idx, flat[idx]
+        n_blocks, cap = _fetch_shape(flat.size)
+        blocks = jnp.pad(flat, (0, n_blocks * FETCH_BLOCK - flat.size))
+        blocks = blocks.reshape(n_blocks, FETCH_BLOCK)
+        # the j-th nonzero block is the first whose running count reaches j
+        running = jnp.cumsum(jnp.any(blocks != 0, axis=1), dtype=jnp.int32)
+        ids = jnp.searchsorted(
+            running, jnp.arange(1, cap + 1, dtype=jnp.int32)
+        ).astype(jnp.int32)
+        got = blocks[ids].astype(jnp.uint32)  # ids past the count clamp
+        bits = 8 * packed.dtype.itemsize
+        per = 32 // bits
+        got = got.reshape(cap, FETCH_BLOCK // per, per)
+        words = got[..., 0]
+        for j in range(1, per):
+            words = words | (got[..., j] << jnp.uint32(bits * j))
+        return jnp.concatenate([
+            running[-1:].astype(jnp.uint32), ids.astype(jnp.uint32),
+            words.reshape(-1),
+        ])
 
 
 @jax.jit
@@ -219,17 +245,31 @@ def or_words(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         return a | b
 
 
-def sparse_nonzero(packed_dev) -> tuple[np.ndarray, np.ndarray]:
-    """(indices, values) of nonzero bytes in a device packed-bit array."""
-    nnz = int(count_nonzero_bytes(packed_dev))
-    if nnz == 0:
-        return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.uint8)
-    # Round k up to limit jit specializations.
-    k = 1 << max(6, (nnz - 1).bit_length())
-    idx, vals = gather_nonzero_bytes(packed_dev, k)
-    idx = np.asarray(idx)[:nnz].astype(np.int64)
-    vals = np.asarray(vals)[:nnz]
-    return idx, vals
+def sparse_nonzero(
+    packed_dev, counts: dict | None = None
+) -> tuple[np.ndarray, np.ndarray]:
+    """(flat indices ascending, int64; values) of the nonzero elements of
+    a device packed-bit plane.  ``counts``, if given, gains the plane's
+    nonzero blocks (``fetch_blocks``) and whether it was downloaded whole
+    (``fetch_overflows``)."""
+    out = np.asarray(fetch_nonzero_blocks(packed_dev))
+    n = int(out[0])
+    _, cap = _fetch_shape(packed_dev.size)
+    overflow = n > cap
+    if overflow:
+        flat = np.asarray(packed_dev).reshape(-1)
+        idx = np.flatnonzero(flat)
+        vals = flat[idx]
+    else:
+        blocks = out[1 + cap:].view(packed_dev.dtype)[: n * FETCH_BLOCK]
+        pos = np.flatnonzero(blocks)
+        vals = blocks[pos]
+        idx = out[1 + pos // FETCH_BLOCK].astype(np.int64) * FETCH_BLOCK
+        idx += pos % FETCH_BLOCK
+    if counts is not None:
+        counts["fetch_blocks"] = counts.get("fetch_blocks", 0) + n
+        counts["fetch_overflows"] = counts.get("fetch_overflows", 0) + overflow
+    return idx.astype(np.int64, copy=False), vals
 
 
 @partial(jax.jit, static_argnames=("k",))

@@ -1,10 +1,12 @@
 """Sparse match-result decoding: device packed bits -> host byte offsets.
 
 Companion to scan_jnp.sparse_nonzero: the device keeps the dense packed
-match plane; the host receives only (index, value) pairs for its nonzero
-bytes/words and decodes absolute match end-offsets from the coordinates.
-Transfer cost is O(matches), not O(corpus/8): the host link carries the
-matches, not a dense plane the size of the shard.
+match plane and sends back only its blocks that hold a nonzero element
+(up to scan_jnp.FETCH_CAP of them, in one transfer; a plane with more is
+downloaded whole).  The host turns them into (index, value) pairs for
+the nonzero bytes/words, and this module decodes absolute match
+end-offsets from the coordinates.  For sparse matches the host link
+carries the matched blocks, not a dense plane the size of the shard.
 """
 
 from __future__ import annotations

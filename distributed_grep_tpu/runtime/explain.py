@@ -97,9 +97,11 @@ def summarize_events(events: list[dict]) -> dict:
                 row["matches"] += int(args.get("matches", 0))
                 # backend compiles the scans waited on (scan_span)
                 row["compiles"] += int(args.get("compiles", 0))
-                # device scans: boundary lines re-matched on the host, and
-                # the host matcher passes of stitch and confirm together
-                for k in ("stitch_lines", "host_line_passes"):
+                # device scans: boundary lines re-matched on the host, the
+                # host matcher passes of stitch and confirm together, and
+                # the result fetch's nonzero blocks and whole-plane downloads
+                for k in ("stitch_lines", "host_line_passes",
+                          "fetch_blocks", "fetch_overflows"):
                     if k in args:
                         row[k] = row.get(k, 0) + int(args[k])
                 # which engine ran the scan (utils/spans.scan_record):

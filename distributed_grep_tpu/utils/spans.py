@@ -244,7 +244,7 @@ def instant(name: str, cat: str = "task", **args) -> None:
 # Engine stats keys promoted into scan telemetry records when present
 # (ops/engine.py / ops/device_scan.py populate them per scan).
 _SCAN_STAT_KEYS = ("candidates", "end_offsets", "stitch_lines",
-                   "host_line_passes")
+                   "host_line_passes", "fetch_blocks", "fetch_overflows")
 
 
 def count_compile() -> None:

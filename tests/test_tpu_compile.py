@@ -184,6 +184,26 @@ def test_approx_compiles(one_chip):
         interpret=False))
 
 
+@pytest.mark.parametrize("plane", ["words", "lane_bytes"])
+def test_fetch_step_compiles(one_chip, plane):
+    """The result fetch's device step over a full segment's plane: a
+    Pallas word plane, and the XLA routes' packed lane bytes."""
+    import jax
+
+    from distributed_grep_tpu.ops import scan_jnp
+
+    lay, _ = _tiles(one_chip)
+    if plane == "words":
+        shape, dtype = (lay.chunk // 32, lay.lanes // 128, 128), np.uint32
+    else:
+        shape, dtype = (lay.chunk, lay.lanes // 8), np.uint8
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    out = scan_jnp.fetch_nonzero_blocks.lower(x).compile().out_info
+    _, cap = scan_jnp._fetch_shape(int(np.prod(shape)))
+    words = cap * scan_jnp.FETCH_BLOCK * np.dtype(dtype).itemsize // 4
+    assert out.shape == (1 + cap + words,) and out.dtype == np.uint32
+
+
 @pytest.fixture(scope="module")
 def mesh_2x2(topo):
     from jax.sharding import Mesh
